@@ -468,7 +468,7 @@ def merge_disk_native(directory: str, newer, older,
     from ..native import get_lib
 
     lib = get_lib()
-    if lib is None or not hasattr(lib, "bucket_merge_stream"):
+    if lib is None:
         return None
     tn = _table_of(newer)
     to = _table_of(older)

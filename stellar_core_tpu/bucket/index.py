@@ -138,7 +138,7 @@ def _native_bloom_fill(keys_blob, koff, klen,
     from ..native import get_lib
 
     lib = get_lib()
-    if lib is None or not hasattr(lib, "bloom_fill"):
+    if lib is None:
         return None
     words = np.zeros(n_blocks, np.uint64)
     lib.bloom_fill(
@@ -177,7 +177,7 @@ def _native_bloom_check(bloom: "BloomFilter",
     from ..native import get_lib
 
     lib = get_lib()
-    if lib is None or not hasattr(lib, "bloom_check") or not kbs:
+    if lib is None or not kbs:
         return None
     probes, p_off, p_len = _probe_table(kbs)
     hits = np.zeros(len(kbs), np.int32)
@@ -346,7 +346,7 @@ def _native_lower_bound(idx: DiskBucketIndex,
     from ..native import get_lib
 
     lib = get_lib()
-    if lib is None or not hasattr(lib, "bucket_lower_bound"):
+    if lib is None:
         return None
     probes, p_off, p_len = _probe_table(kbs)
     out = np.zeros(len(kbs), np.int64)

@@ -303,3 +303,35 @@ def verify(pubkey: bytes, signature: bytes, message: bytes) -> bool:
     # non-canonical R: the computed encoding is canonical)
     rp = point_add(scalar_mult(s, to_extended(B)), scalar_mult(h, point_neg(a)))
     return encode_point(rp) == r_bytes
+
+
+def edge_vectors() -> list[tuple[bytes, bytes, bytes, str]]:
+    """libsodium edge inputs as (pubkey, sig, msg, label): one valid
+    signature, then forged, small-order, non-canonical, malleable and
+    off-curve variants of it.  Only the ``"valid"`` row verifies; every
+    verifier tier must agree with :func:`verify` on all of them."""
+    seed = hashlib.sha256(b"edge0").digest()
+    msg = hashlib.sha256(b"edge-msg0").digest()
+    pk, sig = public_from_seed(seed), sign(seed, msg)
+    out = [(pk, sig, msg, "valid"),
+           (pk, sig[:-1] + bytes([sig[-1] ^ 1]), msg, "bad-sig")]
+    # small-order A (all blacklist encodings), structurally valid sig
+    for j, enc in enumerate(SMALL_ORDER_ENCODINGS):
+        out.append((enc, sig, msg, f"small-order-A-{j}"))
+    # small-order R
+    for j, enc in enumerate(SMALL_ORDER_ENCODINGS):
+        out.append((pk, enc + sig[32:], msg, f"small-order-R-{j}"))
+    # non-canonical A and R: y >= p (y = p + 1 encodes like (0,1) + p)
+    nc = int.to_bytes(P + 1, 32, "little")
+    out.append((nc, sig, msg, "non-canonical-A"))
+    out.append((pk, nc + sig[32:], msg, "non-canonical-R"))
+    # s >= L (malleability): s' = s + L
+    s = int.from_bytes(sig[32:], "little")
+    out.append((pk, sig[:32] + int.to_bytes(s + L, 32, "little"), msg,
+                "malleable-s"))
+    # off-curve A (a y with no valid x)
+    y = 2
+    while _recover_x(y, 0) is not None:
+        y += 1
+    out.append((int.to_bytes(y, 32, "little"), sig, msg, "off-curve-A"))
+    return out

@@ -405,7 +405,7 @@ def _check_native(contractor: _Contractor, interrupt, max_calls: int = 0):
     from .. import native as native_mod
 
     lib = native_mod.get_lib()
-    if lib is None or not hasattr(lib, "quorum_enum_check"):
+    if lib is None:
         return None
     import ctypes
 

@@ -30,16 +30,19 @@ class Application:
         self.clock = clock
         self.config = config
         # resolve "auto" device backends once, before any subsystem reads
-        # them: default-on TPU when a device answers the (never-killed,
-        # bounded-wait) subprocess probe, CPU tiers otherwise
-        if "auto" in (config.CRYPTO_BACKEND, config.SCP_TALLY_BACKEND):
-            from ..utils.device import device_available
+        # them, from this process's own JAX backend (utils/device.py)
+        from ..utils import device
+        from ..utils.logging import get_logger
 
-            alive = device_available()
-            if config.CRYPTO_BACKEND == "auto":
-                config.CRYPTO_BACKEND = "tpu" if alive else "cpu"
-            if config.SCP_TALLY_BACKEND == "auto":
-                config.SCP_TALLY_BACKEND = "tensor" if alive else "host"
+        device.enable_compilation_cache()
+        crypto, tally = device.resolve_auto_backends()
+        if config.CRYPTO_BACKEND == "auto":
+            config.CRYPTO_BACKEND = crypto
+        if config.SCP_TALLY_BACKEND == "auto":
+            config.SCP_TALLY_BACKEND = tally
+        get_logger("Perf").info(
+            "device backends: CRYPTO_BACKEND=%s SCP_TALLY_BACKEND=%s",
+            config.CRYPTO_BACKEND, config.SCP_TALLY_BACKEND)
         self.metrics = MetricsRegistry(clock)
         # flight recorder: span ring + slow-close watchdog (utils/tracing)
         from ..utils.tracing import Tracer

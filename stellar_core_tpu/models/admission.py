@@ -108,7 +108,7 @@ def multi_validator_tally(qt: Q.QSetTensor, voted, accepted):
 
 def bench_sharded(n_devices: int, n_sigs: int = 100_000,
                   n_validators: int = 64, n_candidates: int = 64,
-                  reps: int = 1, workload_npz: str | None = None) -> dict:
+                  reps: int = 1) -> dict:
     """Bench-shaped multi-chip admission: shard a ``n_sigs`` verify batch
     (DP) and a ``n_validators`` ballot tally (validator-parallel) over an
     n-device mesh; return timings + per-device throughput.
@@ -126,23 +126,18 @@ def bench_sharded(n_devices: int, n_sigs: int = 100_000,
     dp = dp_of(mesh)
     rep = replicated(mesh)
 
-    # -- signature workload (reuse a pre-signed corpus when available) ----
-    if workload_npz:
-        d = np.load(workload_npz)
-        pk, sg, mg = d["pk"][:n_sigs], d["sg"][:n_sigs], d["mg"][:n_sigs]
-        assert pk.shape[0] == n_sigs, "workload smaller than n_sigs"
-    else:
-        from ..crypto import SecretKey, sha256
+    # -- signature workload ------------------------------------------------
+    from ..crypto import SecretKey, sha256
 
-        keys = [SecretKey(sha256(b"mcb%d" % i)) for i in range(64)]
-        rng = np.random.default_rng(7)
-        mg = rng.integers(0, 256, (n_sigs, 32), dtype=np.uint8)
-        pk = np.empty((n_sigs, 32), np.uint8)
-        sg = np.empty((n_sigs, 64), np.uint8)
-        for i in range(n_sigs):
-            k = keys[i % 64]
-            pk[i] = np.frombuffer(k.public_key().raw, np.uint8)
-            sg[i] = np.frombuffer(k.sign(bytes(mg[i])), np.uint8)
+    keys = [SecretKey(sha256(b"mcb%d" % i)) for i in range(64)]
+    rng = np.random.default_rng(7)
+    mg = rng.integers(0, 256, (n_sigs, 32), dtype=np.uint8)
+    pk = np.empty((n_sigs, 32), np.uint8)
+    sg = np.empty((n_sigs, 64), np.uint8)
+    for i in range(n_sigs):
+        k = keys[i % 64]
+        pk[i] = np.frombuffer(k.public_key().raw, np.uint8)
+        sg[i] = np.frombuffer(k.sign(bytes(mg[i])), np.uint8)
     pk, sg, mg = (jax.device_put(jnp.asarray(x), dp)
                   for x in (pk, sg, mg))
 

@@ -3,9 +3,9 @@
 host-side merge/scan compute stays native; JAX/Pallas is the device
 tier).
 
-The library builds on first use with g++ (baked into the image) and
-caches the .so next to the sources; every caller has a pure-Python
-fallback, so a missing toolchain degrades gracefully.
+The libraries build on first use with g++ from the committed sources
+and are cached next to them (gitignored, never committed); every caller
+has a pure-Python fallback, so a missing toolchain degrades gracefully.
 """
 from __future__ import annotations
 
@@ -37,19 +37,24 @@ def _src_digest(srcs) -> str:
     return h.hexdigest()
 
 
-def _write_srchash(so: str, srcs) -> None:
-    tmp = f"{so}.srchash.{os.getpid()}.tmp"
-    with open(tmp, "w") as f:
+def _install(tmp: str, so: str, srcs) -> None:
+    """Move a freshly built ``tmp`` into place as ``so``, then its
+    sidecar: a reader between the two sees a stale sidecar and rebuilds,
+    never a fresh sidecar over an old .so.  Each step is an atomic
+    replace of a pid-unique temp, so concurrent first builds (test
+    workers) cannot interleave into a torn file."""
+    side = f"{so}.srchash.{os.getpid()}.tmp"
+    with open(side, "w") as f:
         f.write(_src_digest(srcs))
-    os.replace(tmp, so + ".srchash")
+    os.replace(tmp, so)
+    os.replace(side, so + ".srchash")
 
 
 def _stale(srcs, so: str) -> bool:
     """Content-hash staleness: each built .so carries a ``.srchash``
-    sidecar recording its sources' digest.  mtimes are useless for the
-    prebuilt kernels shipped in the tree — git writes checkout files in
-    arbitrary order, so a source edit without a rebuild could win the
-    mtime race and load an outdated consensus kernel silently."""
+    sidecar recording its sources' digest.  mtimes are useless here — a
+    source edit without a rebuild could win the mtime race and load an
+    outdated consensus kernel silently."""
     if not os.path.exists(so):
         return True
     try:
@@ -60,14 +65,14 @@ def _stale(srcs, so: str) -> bool:
 
 
 def _build() -> bool:
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         r = subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-o", _SO + ".tmp"] + _SRCS,
+            ["g++", "-O2", "-shared", "-fPIC", "-o", tmp] + _SRCS,
             capture_output=True, timeout=120)
         if r.returncode != 0:
             return False
-        os.replace(_SO + ".tmp", _SO)
-        _write_srchash(_SO, _SRCS)
+        _install(tmp, _SO, _SRCS)
         return True
     except Exception:
         return False
@@ -100,29 +105,22 @@ def get_lib() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_int64),
             ctypes.POINTER(ctypes.c_int32),
         ]
-        if hasattr(lib, "bucket_merge_stream"):
-            p64 = ctypes.POINTER(ctypes.c_int64)
-            p32 = ctypes.POINTER(ctypes.c_int32)
-            pu8 = ctypes.POINTER(ctypes.c_uint8)
-            lib.bucket_merge_stream.restype = ctypes.c_int64
-            lib.bucket_merge_stream.argtypes = [
-                ctypes.c_char_p, p64, p32,        # new stream/eoff/elen
-                ctypes.c_char_p, p64, p32, p32,   # new keys/koff/klen/types
-                ctypes.c_int64,                   # n_new
-                ctypes.c_char_p, p64, p32,        # old stream/eoff/elen
-                ctypes.c_char_p, p64, p32, p32,   # old keys/koff/klen/types
-                ctypes.c_int64,                   # n_old
-                ctypes.c_char_p,                  # out_path (NULL = no file)
-                p64, p32, p32,                    # out eoff/elen/types
-                pu8, p64, p32,                    # out keys/koff/klen
-                pu8, p64,                         # out_hash32, out_bytes
-            ]
-        if not hasattr(lib, "quorum_enum_check"):
-            # stale prebuilt .so (mtime newer than sources but missing
-            # newer symbols): degrade to the Python tiers rather than
-            # crash callers that only need the older entry points
-            _lib = lib
-            return _lib
+        p64 = ctypes.POINTER(ctypes.c_int64)
+        p32 = ctypes.POINTER(ctypes.c_int32)
+        pu8 = ctypes.POINTER(ctypes.c_uint8)
+        lib.bucket_merge_stream.restype = ctypes.c_int64
+        lib.bucket_merge_stream.argtypes = [
+            ctypes.c_char_p, p64, p32,        # new stream/eoff/elen
+            ctypes.c_char_p, p64, p32, p32,   # new keys/koff/klen/types
+            ctypes.c_int64,                   # n_new
+            ctypes.c_char_p, p64, p32,        # old stream/eoff/elen
+            ctypes.c_char_p, p64, p32, p32,   # old keys/koff/klen/types
+            ctypes.c_int64,                   # n_old
+            ctypes.c_char_p,                  # out_path (NULL = no file)
+            p64, p32, p32,                    # out eoff/elen/types
+            pu8, p64, p32,                    # out keys/koff/klen
+            pu8, p64,                         # out_hash32, out_bytes
+        ]
         lib.quorum_enum_check.restype = ctypes.c_int64
         lib.quorum_enum_check.argtypes = [
             ctypes.c_int32,                      # n_nodes
@@ -145,21 +143,20 @@ def get_lib() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int64),
         ]
-        if hasattr(lib, "bloom_fill"):
-            pu64 = ctypes.POINTER(ctypes.c_uint64)
-            lib.bloom_fill.restype = None
-            lib.bloom_fill.argtypes = [
-                ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
-                pu64, ctypes.c_int64,
-            ]
-            lib.bloom_check.restype = None
-            lib.bloom_check.argtypes = [
-                pu64, ctypes.c_int64,
-                ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int32),
-            ]
+        pu64 = ctypes.POINTER(ctypes.c_uint64)
+        lib.bloom_fill.restype = None
+        lib.bloom_fill.argtypes = [
+            ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
+            ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+            pu64, ctypes.c_int64,
+        ]
+        lib.bloom_check.restype = None
+        lib.bloom_check.argtypes = [
+            pu64, ctypes.c_int64,
+            ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
+            ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int32),
+        ]
         _lib = lib
         return _lib
 
@@ -187,8 +184,7 @@ def _build_extension(src: str, so: str) -> bool:
             capture_output=True, timeout=180)
         if r.returncode != 0:
             return False
-        os.replace(tmp, so)
-        _write_srchash(so, [src])
+        _install(tmp, so, [src])
         return True
     except Exception:
         return False

@@ -336,6 +336,19 @@ def cycle(n: int, passphrase: str = "test simulation network") -> Simulation:
     return sim
 
 
+def tiered_qset(ids: List[bytes], per_org: int) -> dict:
+    """The two-tier qset of ``hierarchical_quorum``: ``ids`` split into
+    orgs of ``per_org`` consecutive validators, a byzantine-safe majority
+    of orgs at the top, an internal 2f+1 inside each org."""
+    n_orgs = len(ids) // per_org
+    orgs = [ids[o * per_org:(o + 1) * per_org] for o in range(n_orgs)]
+    org_sets = [
+        {"threshold": per_org - (per_org - 1) // 3, "validators": members}
+        for members in orgs]
+    return {"threshold": n_orgs - (n_orgs - 1) // 3,
+            "validators": [], "inner_sets": org_sets}
+
+
 def hierarchical_quorum(n_orgs: int, per_org: int = 5,
                         passphrase: str = "test simulation network",
                         persist_dir: Optional[str] = None,
@@ -358,12 +371,8 @@ def hierarchical_quorum(n_orgs: int, per_org: int = 5,
     sim = Simulation(network_passphrase=passphrase)
     seeds = _seeds(n)
     ids = _ids(seeds)
-    orgs = [ids[o * per_org:(o + 1) * per_org] for o in range(n_orgs)]
-    org_sets = [
-        {"threshold": per_org - (per_org - 1) // 3, "validators": members}
-        for members in orgs]
-    qset = {"threshold": n_orgs - (n_orgs - 1) // 3,
-            "validators": [], "inner_sets": org_sets}
+    qset = tiered_qset(ids, per_org)
+    orgs = [org["validators"] for org in qset["inner_sets"]]
     for i, s in enumerate(seeds):
         sim.add_node(s, qset, node_dir=_node_dir(persist_dir, i),
                      **config_kw)

@@ -661,8 +661,7 @@ def _compile_native_schema(roots, build: bool = True) -> None:
     for idx, t in index.values():
         t._nidx = idx
     _native_pack = mod.pack
-    # older prebuilt .so without the batch entry: encode_many degrades
-    _native_pack_many = getattr(mod, "pack_many", None)
+    _native_pack_many = mod.pack_many
 
 
 def encode_many(pairs):

@@ -109,33 +109,64 @@ def test_blake2_vectors():
 class TestAutoBackends:
     def test_auto_resolves_on_application_construction(self, monkeypatch):
         """CRYPTO_BACKEND/SCP_TALLY_BACKEND default to "auto" and resolve
-        via the device probe at Application construction (VERDICT r3 #2:
-        a TPU-native node needs no env flags to use the TPU)."""
+        from the process's own JAX backend at Application construction:
+        the device tiers on a TPU, the host tiers otherwise."""
+        import jax
+
         from stellar_core_tpu.main import Application, test_config
         from stellar_core_tpu.main.config import Config
-        from stellar_core_tpu.utils import device
         from stellar_core_tpu.utils.clock import ClockMode, VirtualClock
 
         assert Config().CRYPTO_BACKEND == "auto"
         assert Config().SCP_TALLY_BACKEND == "auto"
 
-        monkeypatch.setattr(device, "device_available", lambda **kw: True)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         cfg = test_config(CRYPTO_BACKEND="auto", SCP_TALLY_BACKEND="auto")
         app = Application(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)
         assert app.config.CRYPTO_BACKEND == "tpu"
         assert app.config.SCP_TALLY_BACKEND == "tensor"
 
-        monkeypatch.setattr(device, "device_available", lambda **kw: False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
         cfg2 = test_config(CRYPTO_BACKEND="auto", SCP_TALLY_BACKEND="auto")
         app2 = Application(VirtualClock(ClockMode.VIRTUAL_TIME), cfg2)
         assert app2.config.CRYPTO_BACKEND == "cpu"
         assert app2.config.SCP_TALLY_BACKEND == "host"
 
-    def test_explicit_override_respected(self):
+    def test_explicit_override_respected(self, monkeypatch):
+        import jax
+
         from stellar_core_tpu.main import Application, test_config
         from stellar_core_tpu.utils.clock import ClockMode, VirtualClock
 
-        cfg = test_config()  # pins cpu/host: no probe, no resolution
+        # an explicit setting wins over what "auto" would resolve to
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = test_config()  # pins cpu/host
         app = Application(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)
         assert app.config.CRYPTO_BACKEND == "cpu"
         assert app.config.SCP_TALLY_BACKEND == "host"
+
+
+def test_compilation_cache_placement(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the cache is
+    the one fixed directory inside the checkout."""
+    import os
+
+    import jax
+
+    from stellar_core_tpu.utils import device
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        env_dir = str(tmp_path / "cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert device.enable_compilation_cache() == env_dir
+        assert jax.config.jax_compilation_cache_dir == env_dir
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        got = device.enable_compilation_cache()
+        assert got == device.DEFAULT_CACHE_DIR \
+            == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

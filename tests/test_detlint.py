@@ -348,7 +348,7 @@ def touch():
 
 
 def test_repo_lock_annotations_are_honoured():
-    """The real bucket pipeline / native loader / device probe carry
+    """The real bucket pipeline / native loader / quorum bridge carry
     guarded-by annotations and every mutation is inside its lock."""
     findings = [f for f in lint_repo()
                 if f.rule.startswith("lock-")]

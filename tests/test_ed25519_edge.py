@@ -11,47 +11,18 @@ from stellar_core_tpu.crypto import SecretKey, sha256
 from stellar_core_tpu.crypto import ed25519 as ed
 from stellar_core_tpu.crypto import ed25519_ref as ref
 
-
-def _valid_triple(i=0):
-    sk = SecretKey(sha256(b"edge%d" % i))
-    msg = sha256(b"edge-msg%d" % i)
-    return sk.public_key().raw, sk.sign(msg), msg
+# (pubkey, sig, msg, label) edge inputs.  Expected verdicts come from the
+# spec; the point of the tests is four-way agreement.
+VECTORS = ref.edge_vectors()
 
 
-def _vectors():
-    """(pubkey, sig, msg, label) edge inputs.  Expected verdicts come from
-    the spec; the point of the test is four-way agreement."""
-    out = []
-    pk, sig, msg = _valid_triple()
-    out.append((pk, sig, msg, "valid"))
-    out.append((pk, sig[:-1] + bytes([sig[-1] ^ 1]), msg, "bad-sig"))
-
-    # small-order A (all 10 blacklist encodings), structurally valid sig
-    for j, enc in enumerate(ref.SMALL_ORDER_ENCODINGS):
-        out.append((enc, sig, msg, f"small-order-A-{j}"))
-    # small-order R
-    for j, enc in enumerate(ref.SMALL_ORDER_ENCODINGS):
-        out.append((pk, enc + sig[32:], msg, f"small-order-R-{j}"))
-
-    # non-canonical A: y >= p (y = p + 1 -> encodes like (0,1)+p)
-    nc = int.to_bytes(ref.P + 1, 32, "little")
-    out.append((nc, sig, msg, "non-canonical-A"))
-    out.append((pk, nc + sig[32:], msg, "non-canonical-R"))
-
-    # s >= L (malleability): s' = s + L
-    s = int.from_bytes(sig[32:], "little")
-    s_mall = int.to_bytes(s + ref.L, 32, "little")
-    out.append((pk, sig[:32] + s_mall, msg, "malleable-s"))
-
-    # off-curve A (y with no valid x)
-    y = 2
-    while ref._recover_x(y, 0) is not None:
-        y += 1
-    out.append((int.to_bytes(y, 32, "little"), sig, msg, "off-curve-A"))
-    return out
-
-
-VECTORS = _vectors()
+def test_edge_vectors_sign_like_the_cpu_backend():
+    """The spec signer behind the vectors' valid row is the CPU
+    backend's signer, byte for byte."""
+    sk = SecretKey(sha256(b"edge0"))
+    pk, sig, msg, label = VECTORS[0]
+    assert label == "valid"
+    assert pk == sk.public_key().raw and sig == sk.sign(msg)
 
 
 def test_spec_verdicts():

@@ -22,7 +22,7 @@ guard that keeps PRs from quietly breaking that.  Five rule families:
   protocol-constant lockstep diffed against an explicit manifest,
   CPython API calls inside ``Py_BEGIN/END_ALLOW_THREADS`` regions,
   unchecked Py-allocator NULLs, and ``.srchash`` sidecar currency for
-  every committed kernel ``.so``;
+  every built kernel ``.so``;
 * exception-safety & resource rules (safety.py): silently-swallowing
   broad excepts in consensus scope, non-context-managed fd/mmap opens
   in ``bucket/``, mutable default arguments in consensus functions.

@@ -33,9 +33,9 @@ from .determinism import (
 
 #: modules whose time/env reads are sanctioned by architecture — the
 #: virtual clock IS the time source, tracing/metrics/logging feed only
-#: observability, the scheduler budgets wall time, device probes are
-#: host-local, and main/config.py is the one sanctioned os.environ
-#: boundary.  Functions here are never taint sources or carriers.
+#: observability, the scheduler budgets wall time, the compile-cache
+#: placement is host-local, and main/config.py is the one sanctioned
+#: os.environ boundary.  Functions here are never taint sources or carriers.
 SANCTIONED_MODULES = frozenset({
     f"{PACKAGE}/utils/clock.py",
     f"{PACKAGE}/utils/tracing.py",

@@ -1,6 +1,6 @@
 """Lock-discipline rules (family b) for the threaded subsystems
-(bucket merge pipeline, native library loader, device probe, quorum
-intersection bridge).
+(bucket merge pipeline, native library loader, quorum intersection
+bridge).
 
 Convention: a shared field declares its lock with a trailing comment on
 its (ann-)assignment line::
@@ -20,8 +20,7 @@ lock-order             two locks acquired in opposite nesting orders
                        within one file — the classic ABBA deadlock
                        shape.  Per-file on purpose: lock names are only
                        unambiguous inside their defining module
-                       (`_lock` in native/__init__.py and `_lock` in
-                       utils/device.py are different objects).
+                       (two files may each define their own `_lock`).
 lock-unknown-guard     a guarded-by annotation naming a lock that is
                        never acquired anywhere in the file (typo guard).
 """

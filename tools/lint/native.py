@@ -29,10 +29,10 @@ native-null-unchecked
     by hand).  ``return <alloc>(...)`` propagates to the caller and is
     exempt.
 native-srchash
-    Every committed ``.so`` must carry a ``.srchash`` sidecar matching
+    Every built ``.so`` must carry a ``.srchash`` sidecar matching
     the sha256 of its sources (the loader's content-hash staleness
     contract, native/__init__.py) — a stale sidecar means a stale
-    consensus kernel could load silently after checkout.
+    consensus kernel could load silently after a source edit.
 
 Comments and string literals are masked before token scanning (kernel
 comments legitimately NAME Py* functions); lockstep patterns run on the
@@ -512,9 +512,9 @@ def check_srchash(root: str = REPO) -> List[Finding]:
                 context="<native>",
                 message=(f"{name}.srchash is "
                          f"{'missing' if recorded is None else 'stale'}"
-                         " — rebuild the kernel and commit the .so with "
-                         "its sidecar (a stale consensus kernel must "
-                         "never load)"),
+                         " — delete the .so so the loader rebuilds it "
+                         "with its sidecar (a stale consensus kernel "
+                         "must never load)"),
                 line_text=""))
     return findings
 
