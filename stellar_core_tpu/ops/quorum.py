@@ -212,6 +212,32 @@ def federated_ratify(
 
 
 # ---------------------------------------------------------------------------
+# the live tallies (scp/tally.py): one program per process and shape
+# ---------------------------------------------------------------------------
+# Called eagerly, federated_ratify's while_loop closes over fresh
+# functions on every call, so JAX traces and builds a new executable each
+# time.  Jitted at module level, a program is keyed on the argument
+# shapes alone: the qsets are arguments, never closure constants.
+
+@jax.jit
+def tally_accept(local_qs: QSetTensor, qsets: QSetTensor,
+                 accepted: jnp.ndarray,
+                 vote_or_accept: jnp.ndarray) -> jnp.ndarray:
+    """``Slot::federatedAccept`` for one statement: accepted (1, N) is
+    v-blocking, or vote_or_accept (1, N) holds a quorum.  bool ()."""
+    return (is_v_blocking(local_qs, accepted)[0]
+            | federated_ratify(local_qs, qsets, vote_or_accept)[0])
+
+
+@jax.jit
+def tally_ratify(local_qs: QSetTensor, qsets: QSetTensor,
+                 voted: jnp.ndarray) -> jnp.ndarray:
+    """``Slot::federatedRatify`` for one statement: voted (1, N) holds a
+    quorum.  bool ()."""
+    return federated_ratify(local_qs, qsets, voted)[0]
+
+
+# ---------------------------------------------------------------------------
 # host-side construction from python quorum-set descriptions
 # ---------------------------------------------------------------------------
 

@@ -6,8 +6,10 @@ Per slot, the engine keeps a QSetTensor over the current envelope
 universe, rebuilt only when the (node -> qset-hash) map changes.  Each
 ``Slot.federated_accept/ratify`` call evaluates its statement predicates
 on host (cheap python over ≤N statements) and runs the threshold/fixpoint
-math as one device program.  Quorum sets deeper than 2 levels have no
-tensor form (ref MAXIMUM_QUORUM_NESTING_LEVEL=4,
+math as one device program: ``ops.quorum.tally_accept``/``tally_ratify``,
+jitted at module level, so a program is built once per process and
+shape (N, K) and reused by every later slot.  Quorum sets deeper than 2
+levels have no tensor form (ref MAXIMUM_QUORUM_NESTING_LEVEL=4,
 src/scp/QuorumSetUtils.cpp:16) — those slots fall back to the exact host
 evaluation in scp/local_node.py, which is also the differential oracle in
 "both" mode.
@@ -25,6 +27,12 @@ TENSOR = "tensor"
 BOTH = "both"  # tensor path + host oracle, assert equal (sim tests)
 
 
+# (entry name, local and batched inner_mem shapes) of every tally this
+# process ran.  Process-wide like the jit cache it mirrors: a key not in
+# it builds a device program on its first call
+_SHAPES_SEEN: set = set()
+
+
 class TallyMismatch(AssertionError):
     pass
 
@@ -36,6 +44,7 @@ class TallyEngine:
         self._cache_key: Optional[Tuple] = None
         self._tensors = None  # (local_qs, qsets, node_order)
         self.tensor_tallies = 0
+        self.programs_built = 0  # calls whose shape was new to the process
         self.host_fallbacks = 0
 
     # -- tensor (re)construction -------------------------------------------
@@ -86,6 +95,15 @@ class TallyEngine:
 
     # -- tallies ------------------------------------------------------------
 
+    def _run(self, entry, local_qs, qsets, *votes) -> bool:
+        shape = (entry.__name__, local_qs.inner_mem.shape,
+                 qsets.inner_mem.shape)
+        if shape not in _SHAPES_SEEN:
+            _SHAPES_SEEN.add(shape)
+            self.programs_built += 1
+        self.tensor_tallies += 1
+        return bool(entry(local_qs, qsets, *votes))
+
     def federated_accept(self, voted_predicate: Callable,
                          accepted_predicate: Callable,
                          envelopes: Dict[bytes, object]) -> Optional[bool]:
@@ -97,7 +115,6 @@ class TallyEngine:
             self.host_fallbacks += 1
             return None
         from ..ops import quorum as Q
-        import jax.numpy as jnp
 
         local_qs, qsets, order = t
         accepted = np.zeros((1, len(order)), np.bool_)
@@ -109,12 +126,8 @@ class TallyEngine:
             acc = accepted_predicate(env.statement)
             accepted[0, i] = acc
             vote_or_accept[0, i] = acc or voted_predicate(env.statement)
-        vblock = bool(Q.is_v_blocking(
-            local_qs, jnp.asarray(accepted))[0])
-        ratified = bool(Q.federated_ratify(
-            local_qs, qsets, jnp.asarray(vote_or_accept))[0])
-        verdict = vblock or ratified
-        self.tensor_tallies += 1
+        verdict = self._run(Q.tally_accept, local_qs, qsets, accepted,
+                            vote_or_accept)
         if self.backend == BOTH:
             host = self._host_accept(voted_predicate, accepted_predicate,
                                      envelopes)
@@ -133,7 +146,6 @@ class TallyEngine:
             self.host_fallbacks += 1
             return None
         from ..ops import quorum as Q
-        import jax.numpy as jnp
 
         local_qs, qsets, order = t
         voted = np.zeros((1, len(order)), np.bool_)
@@ -141,9 +153,7 @@ class TallyEngine:
             env = envelopes.get(n)
             if env is not None and voted_predicate(env.statement):
                 voted[0, i] = True
-        verdict = bool(Q.federated_ratify(
-            local_qs, qsets, jnp.asarray(voted))[0])
-        self.tensor_tallies += 1
+        verdict = self._run(Q.tally_ratify, local_qs, qsets, voted)
         if self.backend == BOTH:
             host = self._host_ratify(voted_predicate, envelopes)
             if host != verdict:
