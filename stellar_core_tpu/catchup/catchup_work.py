@@ -26,7 +26,7 @@ from __future__ import annotations
 import hashlib
 import os
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from ..bucket.bucket_list import BucketList
 from ..ledger.ledger_txn import LedgerTxn
@@ -38,15 +38,59 @@ from .. import history as H
 
 
 class CatchupConfiguration:
-    """MINIMAL: buckets at the target checkpoint only; COMPLETE: replay
-    every ledger from the local LCL (ref CatchupConfiguration modes)."""
+    """What a catchup reaches: ``to_ledger``, and how (ref
+    src/catchup/CatchupConfiguration.h, CatchupRange.cpp).
+
+    ``count`` is upstream's LEDGER-COUNT, ``catchup <to>/<count>``: the
+    least number of ledgers replayed, ending at ``to_ledger``.  A node at
+    genesis applies the buckets of the latest checkpoint at or before
+    ``to_ledger - count`` and replays every ledger after it up to
+    ``to_ledger``: ``127/64`` applies the buckets of 63 and replays
+    64-127, and so does ``127/40`` (87 lies in the checkpoint 64-127).  A
+    ``count`` whose first ledger falls in the first checkpoint replays
+    from genesis, and ``count`` 0 with ``to_ledger`` a checkpoint applies
+    its buckets and replays nothing.  A node past genesis replays from
+    its LCL whatever ``count`` says.
+
+    Without ``count``, ``mode`` keeps this node's older meaning: MINIMAL
+    applies the buckets of the latest checkpoint at or before
+    ``to_ledger`` (when it lies past the LCL) and replays the tail after
+    it, which is upstream's ``count`` 0; COMPLETE replays every ledger
+    from the LCL.  Departures from upstream: ``to_ledger`` and ``count``
+    are numbers (upstream also takes ``current`` and ``max``)."""
 
     MINIMAL = "minimal"
     COMPLETE = "complete"
 
-    def __init__(self, to_ledger: int, mode: str = MINIMAL):
+    def __init__(self, to_ledger: int, mode: str = MINIMAL,
+                 count: Optional[int] = None):
+        if count is not None and count < 0:
+            raise ValueError(f"catchup count {count} < 0")
         self.to_ledger = to_ledger
         self.mode = mode
+        self.count = count
+
+    def plan(self, lcl: int, hm) -> Tuple[Optional[int], int]:
+        """(the checkpoint whose buckets are applied, or None; the first
+        ledger replayed) for a node whose last closed ledger is ``lcl``.
+        Replay runs from there to ``to_ledger``."""
+        from ..ledger.ledger_manager import GENESIS_LEDGER_SEQ
+
+        to = self.to_ledger
+        if self.count is None:
+            cp = hm.latest_checkpoint_at_or_before(to)
+            if self.mode == self.MINIMAL and cp > lcl:
+                return cp, cp + 1
+            return None, lcl + 1
+        if lcl > GENESIS_LEDGER_SEQ or self.count >= to - lcl:
+            return None, lcl + 1
+        if self.count == 0 and hm.is_last_ledger_in_checkpoint(to):
+            return to, to + 1
+        first = hm.first_ledger_in_checkpoint(
+            hm.checkpoint_containing(to - self.count + 1))
+        if first == GENESIS_LEDGER_SEQ:
+            return None, lcl + 1
+        return first - 1, first
 
 
 def _archive_pool(app, archive):
@@ -518,7 +562,12 @@ class ApplyCheckpointsWork(BasicWork):
     (complete catchup / the replay tail; ref ApplyCheckpointWork +
     ApplyLedgerWork).  Tx sets are pre-downloaded (DownloadTxSetsWork)
     when driven by CatchupWork; direct users fall back to a synchronous
-    load."""
+    load.
+
+    With the TPU crypto backend, the signatures of every ledger of a
+    checkpoint are verified in ONE device batch when replay enters it
+    (``_verify_checkpoint``), and each close is handed its ledger's
+    verdicts instead of dispatching its own ~1000-row batch."""
 
     def __init__(self, app, archive, headers: Dict[int, object],
                  first: int, last: int,
@@ -534,6 +583,8 @@ class ApplyCheckpointsWork(BasicWork):
         self._tx_sets = dict(tx_sets) if tx_sets is not None else {}
         self._loaded_cps: set = set()
         self._next = first
+        # seq -> (TxSetFrame, verdicts) for the checkpoint being replayed
+        self._verified: Dict[int, tuple] = {}
 
     def _ensure_checkpoint(self, seq: int) -> bool:
         """Lazily load the tx-set chunk covering ``seq`` — one checkpoint
@@ -556,8 +607,47 @@ class ApplyCheckpointsWork(BasicWork):
         self._loaded_cps.add(cp)
         return True
 
-    def on_run(self) -> State:
+    def _frame(self, seq: int):
+        """Ledger ``seq``'s tx set as a frame.  Popped: an applied
+        ledger's decoded transactions are never needed again — keeps
+        replay memory bounded by one checkpoint chunk."""
         from ..herder.tx_set import TxSetFrame
+
+        xdr_set = self._tx_sets.pop(seq, None)
+        if xdr_set is None:
+            xdr_set = T.TransactionSet.make(
+                previousLedgerHash=self.headers[seq].header
+                .previousLedgerHash, txs=[])
+        return TxSetFrame.make_from_wire(self.app.config.network_id(),
+                                         xdr_set)
+
+    def _verify_checkpoint(self, seq: int) -> bool:
+        """Frames for ledgers ``seq`` to the end of its checkpoint (or
+        ``last``), and their signatures verified in one dispatch through
+        the device leg the close's phase 0 uses; False when a header of
+        the range is missing."""
+        from ..herder.tx_set import verify_signature_batch
+
+        app = self.app
+        end = min(self.last, app.history_manager.checkpoint_containing(seq))
+        if any(s not in self.headers for s in range(seq, end + 1)):
+            return False
+        frames = [(s, self._frame(s)) for s in range(seq, end + 1)]
+        with app.tracer.span("catchup.replay.sigbatch",
+                             first=seq, last=end) as sp:
+            per_set = [f.collect_signature_batch()[0] for _, f in frames]
+            triples = [t for ts in per_set for t in ts]
+            verdicts = verify_signature_batch(triples, app.tracer)
+            for (s, frame), ts in zip(frames, per_set):
+                self._verified[s] = (frame, {t: verdicts[t] for t in ts})
+        app.metrics.timer("catchup.replay.sigbatch").update(sp.seconds)
+        if triples:
+            app.metrics.counter("catchup.replay.sig_dispatches").inc()
+            app.metrics.counter("catchup.replay.sigs_batched").inc(
+                len(triples))
+        return True
+
+    def on_run(self) -> State:
         from ..ledger.ledger_manager import LedgerCloseData
 
         app = self.app
@@ -569,20 +659,19 @@ class ApplyCheckpointsWork(BasicWork):
             return State.FAILURE
         if not self._prefetched and not self._ensure_checkpoint(seq):
             return State.FAILURE
+        if seq not in self._verified and \
+                app.config.CRYPTO_BACKEND == "tpu" and \
+                not self._verify_checkpoint(seq):
+            return State.FAILURE
+        frame, verdicts = self._verified.pop(seq, None) or \
+            (self._frame(seq), None)
         hdr = entry.header
-        # pop: an applied ledger's decoded transactions are never needed
-        # again — keeps replay memory bounded by one checkpoint chunk
-        xdr_set = self._tx_sets.pop(seq, None)
-        if xdr_set is None:
-            xdr_set = T.TransactionSet.make(
-                previousLedgerHash=hdr.previousLedgerHash, txs=[])
-        frame = TxSetFrame.make_from_wire(app.config.network_id(), xdr_set)
         # replayed closes must not re-publish checkpoints: this node has
         # no scp history for them, and writing would clobber the very
         # archive files being read
         with app.history_manager.publish_suppressed():
             app.ledger_manager.close_ledger(
-                LedgerCloseData(seq, frame, hdr.scpValue))
+                LedgerCloseData(seq, frame, hdr.scpValue, verdicts))
         if app.ledger_manager.last_closed_hash() != entry.hash:
             return State.FAILURE  # replay divergence — fail loudly
         app.metrics.counter("catchup.ledger.replayed").inc()
@@ -593,8 +682,8 @@ class ApplyCheckpointsWork(BasicWork):
 class CatchupWork(Work):
     """The top-level DAG (ref CatchupWork.h:44): HAS -> {verified header
     chain ∥ bucket files ∥ tail tx sets} downloaded concurrently ->
-    buckets applied at the anchor checkpoint (minimal) or full replay
-    from the local LCL (complete) -> the post-checkpoint tail replayed.
+    buckets applied at the checkpoint the configuration's ``plan`` names,
+    or none -> every ledger after it (or after the local LCL) replayed.
     Phase wall-times land in catchup.phase.{verify,apply,replay} timers
     (verify = HAS + all downloads + chain verification)."""
 
@@ -631,6 +720,7 @@ class CatchupWork(Work):
         self._phase_t0: Optional[float] = None
         self._use_buckets = False
         self._first_needed = 0
+        self._replay_first = 0
 
     @property
     def phase(self) -> str:
@@ -662,10 +752,10 @@ class CatchupWork(Work):
         # detlint: allow(det-wallclock) metrics-only phase timing
         self._phase_t0 = time.monotonic()
         self._stage = self.STAGE_HAS
-        self._use_buckets = (
-            self.config.mode == CatchupConfiguration.MINIMAL
-            and self.target_checkpoint > lcl)
+        bucket_cp, self._replay_first = self.config.plan(lcl, hm)
+        self._use_buckets = bucket_cp is not None
         if self._use_buckets:
+            self.target_checkpoint = bucket_cp
             self._first_needed = max(
                 hm.first_ledger_in_checkpoint(self.target_checkpoint) - 1,
                 1)
@@ -674,7 +764,7 @@ class CatchupWork(Work):
                 clock=app.clock, retry_backoff=self.retry_backoff)
             self.add_work(self.get_has)
         else:
-            self._first_needed = lcl + 1
+            self._first_needed = self._replay_first
             self.get_has = None
 
     def do_work(self) -> State:
@@ -691,9 +781,7 @@ class CatchupWork(Work):
                     app, self.archive, self.get_has.has,
                     clock=clock, retry_backoff=self.retry_backoff)
                 self.add_work(self.buckets_dl)
-                replay_first = self.target_checkpoint + 1
-            else:
-                replay_first = self._first_needed
+            replay_first = self._replay_first
             # parallel tx-set prefetch only pays off for short tails; a
             # long complete-mode replay would hold every decoded tx in
             # memory at once — beyond the cap, ApplyCheckpointsWork
@@ -720,12 +808,10 @@ class CatchupWork(Work):
             # fall through to schedule the replay
 
         if self._stage == self.STAGE_APPLY:
-            replay_first = (self.target_checkpoint + 1 if self._use_buckets
-                            else self._first_needed)
-            if replay_first <= self.config.to_ledger:
+            if self._replay_first <= self.config.to_ledger:
                 self.add_work(ApplyCheckpointsWork(
                     app, self.archive, self.verify.headers,
-                    replay_first, self.config.to_ledger,
+                    self._replay_first, self.config.to_ledger,
                     tx_sets=(self.txs_dl.tx_sets if self.txs_dl
                              else None)))
                 self._end_phase(self.STAGE_REPLAY)

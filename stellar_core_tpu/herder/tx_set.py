@@ -224,72 +224,22 @@ class TxSetFrame:
                                ) -> Dict[Tuple[bytes, bytes, bytes], bool]:
         """Verify the whole set's signatures as one batch; returns a verdict
         cache keyed by (pubkey, signature, msg) for SignatureChecker.
-
-        ``tracer`` (utils/tracing) splits the device leg into a dispatch
-        span (batch assembly + async JAX dispatch) and a host-wait span
-        (blocking on the device result) — the host-Python-vs-kernel-time
-        attribution ROADMAP item 7 asks about."""
+        ``use_device`` sends the batch down the device leg
+        (``verify_signature_batch``, which spans it on ``tracer``); else
+        the host verifies it row by row."""
         if tracer is None:
             from ..utils.tracing import NULL_TRACER as tracer
         triples, _ = self.collect_signature_batch()
         if not triples:
             return {}
-        verdicts: Dict[Tuple[bytes, bytes, bytes], bool] = {}
         if use_device:
-            import os
+            return verify_signature_batch(triples, tracer)
+        from ..crypto import verify_sig
 
-            import numpy as np
-
-            # kernel tier: the XLA kernel lowers on every backend and is
-            # the safe default; CRYPTO_KERNEL=pallas opts the node into
-            # the Pallas TPU kernel (bench.py probes pallas itself).
-            # Kernel CHOICE is env-driven but both tiers return
-            # bit-identical verdicts, so this read is consensus-neutral.
-            # detlint: allow(det-wallclock)
-            if os.environ.get("CRYPTO_KERNEL", "xla") == "pallas":
-                from ..ops.ed25519_pallas import verify_batch
-            else:
-                from ..ops.ed25519_kernel import verify_batch
-
-            from ..utils.device import pad_signature_batch
-
-            with tracer.span("crypto.sigbatch.dispatch",
-                             n_sigs=len(triples)):
-                n = len(triples)
-                pk = np.frombuffer(
-                    b"".join(t[0] for t in triples),
-                    np.uint8).reshape(n, 32)
-                sg = np.frombuffer(
-                    b"".join(t[1].ljust(64, b"\x00") for t in triples),
-                    np.uint8).reshape(n, 64)
-                mg = np.frombuffer(
-                    b"".join(t[2] for t in triples),
-                    np.uint8).reshape(n, 32)
-                # pad to a fixed batch bucket (repeating real rows) so the
-                # device sees a small closed set of shapes — per-close
-                # batch sizes vary freely and would otherwise force a
-                # recompile every time a new size shows up
-                padded = pad_signature_batch(n)
-                if padded != n:
-                    idx = np.arange(padded) % n
-                    pk, sg, mg = pk[idx], sg[idx], mg[idx]
-                # JAX dispatch is async: this returns as soon as the
-                # computation is enqueued on the device
-                pending = verify_batch(pk, sg, mg)
-            with tracer.span("crypto.sigbatch.host_wait"):
-                # materializing blocks until the device result lands —
-                # the dispatch/host-wait split is the JAX-overhead vs.
-                # kernel-time attribution
-                ok = np.asarray(pending)[:n]
-            for t, v in zip(triples, ok):
-                verdicts[(t[0], t[1], t[2])] = bool(v)
-        else:
-            from ..crypto import verify_sig
-
-            with tracer.span("crypto.sigbatch.cpu",
-                             n_sigs=len(triples)):
-                for pub, sig, msg in triples:
-                    verdicts[(pub, sig, msg)] = verify_sig(pub, sig, msg)
+        verdicts: Dict[Tuple[bytes, bytes, bytes], bool] = {}
+        with tracer.span("crypto.sigbatch.cpu", n_sigs=len(triples)):
+            for pub, sig, msg in triples:
+                verdicts[(pub, sig, msg)] = verify_sig(pub, sig, msg)
         return verdicts
 
     def make_cached_verify(self, verdicts):
@@ -304,6 +254,68 @@ class TxSetFrame:
             return verify_sig(pub, sig, msg)
 
         return verify
+
+
+def verify_signature_batch(triples: Sequence[Tuple[bytes, bytes, bytes]],
+                           tracer=None
+                           ) -> Dict[Tuple[bytes, bytes, bytes], bool]:
+    """The device leg of signature verification: (pubkey, sig, msg)
+    triples -> verdict map, as ONE padded device batch.  Shared by the
+    close's phase 0 (``prevalidate_signatures``, one set), catchup
+    replay (``ApplyCheckpointsWork``, a whole checkpoint's sets) and the
+    overlay's batch of SCP envelope signatures.
+
+    ``tracer`` (utils/tracing) splits it into a dispatch span (batch
+    assembly + async JAX dispatch) and a host-wait span (blocking on the
+    device result) — the host-Python-vs-kernel-time attribution ROADMAP
+    item 7 asks about."""
+    import os
+
+    import numpy as np
+
+    if tracer is None:
+        from ..utils.tracing import NULL_TRACER as tracer
+    if not triples:
+        return {}
+    # kernel tier: the XLA kernel lowers on every backend and is the safe
+    # default; CRYPTO_KERNEL=pallas opts the node into the Pallas TPU
+    # kernel (bench.py probes pallas itself).  Kernel CHOICE is
+    # env-driven but both tiers return bit-identical verdicts, so this
+    # read is consensus-neutral.
+    # detlint: allow(det-wallclock)
+    if os.environ.get("CRYPTO_KERNEL", "xla") == "pallas":
+        from ..ops.ed25519_pallas import verify_batch
+    else:
+        from ..ops.ed25519_kernel import verify_batch
+
+    from ..utils.device import pad_signature_batch
+
+    with tracer.span("crypto.sigbatch.dispatch", n_sigs=len(triples)):
+        n = len(triples)
+        pk = np.frombuffer(
+            b"".join(t[0] for t in triples), np.uint8).reshape(n, 32)
+        sg = np.frombuffer(
+            b"".join(t[1].ljust(64, b"\x00") for t in triples),
+            np.uint8).reshape(n, 64)
+        mg = np.frombuffer(
+            b"".join(t[2] for t in triples), np.uint8).reshape(n, 32)
+        # pad to a fixed batch bucket (repeating real rows) so the device
+        # sees a small closed set of shapes — per-close batch sizes vary
+        # freely and would otherwise force a recompile every time a new
+        # size shows up
+        padded = pad_signature_batch(n)
+        if padded != n:
+            idx = np.arange(padded) % n
+            pk, sg, mg = pk[idx], sg[idx], mg[idx]
+        # JAX dispatch is async: this returns as soon as the computation
+        # is enqueued on the device
+        pending = verify_batch(pk, sg, mg)
+    with tracer.span("crypto.sigbatch.host_wait"):
+        # materializing blocks until the device result lands — the
+        # dispatch/host-wait split is the JAX-overhead vs. kernel-time
+        # attribution
+        ok = np.asarray(pending)[:n]
+    return {(t[0], t[1], t[2]): bool(v) for t, v in zip(triples, ok)}
 
 
 #: op types riding the DEX lane (offers + path payments — everything

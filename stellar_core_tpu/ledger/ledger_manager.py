@@ -30,12 +30,19 @@ _LAST_GC_SEQ = -1  # guarded-by: _GC_SEQ_LOCK
 
 class LedgerCloseData:
     """(ledgerSeq, TxSetFrame, StellarValue) bundle handed from Herder
-    (ref src/herder/LedgerCloseData.h:23)."""
+    (ref src/herder/LedgerCloseData.h:23).
 
-    def __init__(self, ledger_seq: int, tx_set, close_value):
+    ``verdicts``: the set's signature verdicts, computed ahead of the
+    close (catchup replay verifies a whole checkpoint's sets in one
+    device batch), keyed as ``TxSetFrame.prevalidate_signatures``
+    returns them; None makes the close verify its own set."""
+
+    def __init__(self, ledger_seq: int, tx_set, close_value,
+                 verdicts=None):
         self.ledger_seq = ledger_seq
         self.tx_set = tx_set
         self.close_value = close_value  # XDR StellarValue
+        self.verdicts = verdicts
 
 
 class LedgerManager:
@@ -238,11 +245,14 @@ class LedgerManager:
                 self.root.prefetch(prefetch_keys)
             self._phase(phases, "prefetch", sp.seconds)
 
-            # phase 0: batched signature verification on device (P5)
+            # phase 0: batched signature verification on device (P5),
+            # unless the verdicts came with the close
             with tracer.span("ledger.close.verify") as sp:
-                verdicts = tx_set.prevalidate_signatures(
-                    use_device=self.app.config.CRYPTO_BACKEND == "tpu",
-                    tracer=tracer)
+                verdicts = close_data.verdicts
+                if verdicts is None:
+                    verdicts = tx_set.prevalidate_signatures(
+                        use_device=self.app.config.CRYPTO_BACKEND == "tpu",
+                        tracer=tracer)
                 verify = tx_set.make_cached_verify(verdicts)
             self._phase(phases, "verify", sp.seconds)
 

@@ -69,7 +69,22 @@ def cmd_new_db(cfg: Config) -> int:
     return 0
 
 
-def cmd_catchup(cfg: Config, to_ledger: int, mode: str) -> int:
+def parse_catchup_target(text: str):
+    """``<to>`` or upstream's ``<to>/<count>`` -> (to_ledger, count or
+    None)."""
+    to, sep, count = text.partition("/")
+    try:
+        return int(to), (int(count) if sep else None)
+    except ValueError:
+        raise argparse.ArgumentTypeError(
+            f"catchup target {text!r} is not <to> or <to>/<count>")
+
+
+def cmd_catchup(cfg: Config, target, mode: str) -> int:
+    """``catchup <to>/<count>`` replays upstream's range (buckets at the
+    latest checkpoint at or before to - count, then every ledger after
+    it); ``catchup <to> --mode`` keeps its minimal/complete meaning
+    (catchup/catchup_work.CatchupConfiguration)."""
     from ..catchup import CatchupConfiguration, CatchupWork
     from ..work.work import State
 
@@ -79,10 +94,11 @@ def cmd_catchup(cfg: Config, to_ledger: int, mode: str) -> int:
         print(json.dumps({"error": "no HISTORY_ARCHIVES configured"}))
         return 1
     archive = app.history_manager.archives[0]
+    to_ledger, count = target
     work = CatchupWork(app, archive, CatchupConfiguration(
         to_ledger,
         CatchupConfiguration.COMPLETE if mode == "complete"
-        else CatchupConfiguration.MINIMAL))
+        else CatchupConfiguration.MINIMAL, count=count))
     work.start()
     for _ in range(100000):
         work.crank()
@@ -160,7 +176,8 @@ def main(argv=None) -> int:
     sub.add_parser("run")
     sub.add_parser("new-db")
     cu = sub.add_parser("catchup")
-    cu.add_argument("to_ledger", type=int)
+    cu.add_argument("target", type=parse_catchup_target,
+                    metavar="<to>[/<count>]")
     cu.add_argument("--mode", choices=["minimal", "complete"],
                     default="minimal")
     sub.add_parser("publish")
@@ -176,7 +193,7 @@ def main(argv=None) -> int:
     if args.cmd == "new-db":
         return cmd_new_db(cfg)
     if args.cmd == "catchup":
-        return cmd_catchup(cfg, args.to_ledger, args.mode)
+        return cmd_catchup(cfg, args.target, args.mode)
     if args.cmd == "publish":
         return cmd_publish(cfg)
     if args.cmd == "self-check":

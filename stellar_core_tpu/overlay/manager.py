@@ -410,24 +410,10 @@ class OverlayManager:
                           for t in triples)
         if self.app.config.CRYPTO_BACKEND == "tpu" and \
                 len(triples) >= 2 and well_formed:
-            import numpy as np
+            from ..herder.tx_set import verify_signature_batch
 
-            from ..ops.ed25519_kernel import verify_batch
-            from ..utils.device import pad_signature_batch
-
-            n = len(triples)
-            pk = np.frombuffer(b"".join(t[0] for t in triples),
-                               np.uint8).reshape(n, 32)
-            sg = np.frombuffer(b"".join(t[1] for t in triples),
-                               np.uint8).reshape(n, 64)
-            mg = np.frombuffer(b"".join(t[2] for t in triples),
-                               np.uint8).reshape(n, 32)
-            padded = pad_signature_batch(n)
-            if padded != n:
-                idx = np.arange(padded) % n
-                pk, sg, mg = pk[idx], sg[idx], mg[idx]
-            ok = np.asarray(verify_batch(pk, sg, mg))[:n]
-            return [bool(v) for v in ok]
+            verdicts = verify_signature_batch(triples)
+            return [verdicts[(p, s, m)] for p, s, m in triples]
         from ..crypto import verify_sig
 
         return [verify_sig(p, s, m) for p, s, m in triples]

@@ -9,6 +9,8 @@ same hashes with OVERLAY_SIG_BATCH on and off, forged signatures must
 still be rejected through the batched path, and the batch counters
 must surface in /metrics.
 """
+import pytest
+
 from stellar_core_tpu.crypto import SecretKey, sha256
 from stellar_core_tpu.simulation import core
 from stellar_core_tpu.xdr import types as T
@@ -55,12 +57,15 @@ def test_sigbatch_off_parity():
     assert batched == direct
 
 
-def test_verify_triples_matches_scalar_verdicts():
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_verify_triples_matches_scalar_verdicts(backend):
     """_verify_triples is the batch chokepoint: good and forged
     signatures interleaved must come back [True, False, ...] exactly
-    like scalar verify_sig."""
+    like scalar verify_sig, on the host and down the device leg (JAX's
+    CPU backend here)."""
     sim = core(2)
     app = next(iter(sim.nodes.values()))
+    app.config.CRYPTO_BACKEND = backend
     om = app.overlay_manager
     sk = SecretKey(b"\x07" * 32)
     msg_a, msg_b = sha256(b"batch a"), sha256(b"batch b")
