@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..ledger.ledger_txn import LedgerTxn, VIRTUAL_PREFIX, _OFFER_PREFIX
 from ..xdr import types as T
+from .footprint import OfferMemo
 from .planner import ApplyPlan, plan_parallel_apply
 
 
@@ -287,6 +288,10 @@ class ParallelApplyManager:
         from collections import OrderedDict
 
         self._plan_cache: "OrderedDict" = OrderedDict()
+        # resting offers' plan-time derivations, kept across plans
+        # (footprint.OfferMemo): a node's book is mostly the offers its
+        # previous plan decoded.  Main-crank-thread only, as above.
+        self.offer_memo = OfferMemo()
 
     def shutdown(self) -> None:
         if self.executor is not None:
@@ -326,9 +331,7 @@ class ParallelApplyManager:
         if len(apply_order) < 2:
             return
         with LedgerTxn(root) as ltx:
-            plan, stats = plan_parallel_apply(
-                apply_order, ltx,
-                allow_single_native=self.native_wanted)
+            plan, stats = self._plan(apply_order, ltx)
             ltx.rollback()
         self._plan_cache[key] = (plan, stats)
         while len(self._plan_cache) > 4:
@@ -342,13 +345,22 @@ class ParallelApplyManager:
             self.stats["preplan_hits"] += 1
             stats = dict(stats, preplanned=True)
         else:
-            plan, stats = plan_parallel_apply(
-                apply_order, ltx,
-                allow_single_native=self.native_wanted)
+            plan, stats = self._plan(apply_order, ltx)
         self.last_plan_stats = stats
         if plan is None:
             self.stats["unplanned"] += 1
         return plan
+
+    def _plan(self, apply_order, ltx):
+        plan, stats = plan_parallel_apply(
+            apply_order, ltx, allow_single_native=self.native_wanted,
+            memo=self.offer_memo)
+        self.offer_memo.end_plan()
+        metrics = self.app.metrics
+        metrics.counter("apply.plan.book_rows").inc(stats["book_rows"])
+        metrics.counter("apply.plan.book_rows_reused").inc(
+            stats["book_rows_reused"])
+        return plan, stats
 
     # -- execution ----------------------------------------------------------
 

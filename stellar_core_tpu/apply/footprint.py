@@ -89,12 +89,62 @@ class BookMaterialization:
         self.assets: List[object] = []      # the two decoded Asset values
 
 
-class PlanContext:
-    """Shared memoization across one close's footprint pass."""
+class OfferMemo:
+    """Each resting offer's plan-time derivation, kept across plans.
 
-    def __init__(self, ltx):
+    Keyed by ``(key bytes, entry blob)`` exactly as the SQL book scan
+    returns them; the value is ``(row, keys)``: the ``(Fraction price,
+    offerID, kb)`` book row and the keys the offer reaches
+    (``_derive_offer``).  The derivation is a pure function of the
+    entry's bytes, so nothing invalidates it: a changed offer (a partial
+    fill, a new price, a moved sponsorship) has another blob and misses,
+    and a deleted one no longer comes back from the scan.  The offer's
+    direction needs no slot: it is a function of the blob, and the scan
+    already filtered on it.
+
+    Two generations bound it by the live book: after each plan only the
+    entries that plan or the previous one touched are kept.  Owned by
+    one node's ``ParallelApplyManager``; main thread only."""
+
+    __slots__ = ("cur", "prev", "older")
+
+    def __init__(self):
+        self.cur: Dict[Tuple[bytes, bytes], tuple] = {}    # this plan
+        self.prev: Dict[Tuple[bytes, bytes], tuple] = {}   # the last one
+        self.older: Dict[Tuple[bytes, bytes], tuple] = {}  # the one before
+
+    def get(self, key: Tuple[bytes, bytes]) -> Optional[tuple]:
+        d = self.cur.get(key)
+        if d is None:
+            d = self.prev.get(key)
+            if d is None:
+                d = self.older.get(key)
+            if d is not None:
+                self.cur[key] = d
+        return d
+
+    def put(self, key: Tuple[bytes, bytes], derivation: tuple) -> None:
+        self.cur[key] = derivation
+
+    def end_plan(self) -> None:
+        self.older, self.prev, self.cur = self.prev, self.cur, {}
+
+    def __len__(self) -> int:
+        return len(self.cur.keys() | self.prev.keys() | self.older.keys())
+
+
+class PlanContext:
+    """Shared memoization across one close's footprint pass.  ``memo``
+    carries resting offers' derivations across plans (a fresh one when
+    the caller keeps none); ``book_rows`` counts the book rows this
+    plan materialized, ``book_rows_reused`` those the memo served."""
+
+    def __init__(self, ltx, memo: Optional[OfferMemo] = None):
         self.ltx = ltx
         self.books: Dict[Tuple[bytes, bytes], BookMaterialization] = {}
+        self.memo = OfferMemo() if memo is None else memo
+        self.book_rows = 0
+        self.book_rows_reused = 0
 
     # -- order-book expansion ---------------------------------------------
 
@@ -113,16 +163,25 @@ class PlanContext:
         mat = BookMaterialization(pair)
         mat.assets = [selling, buying]
         overrides, root = self.ltx._collect_offer_overrides()
+        memo = self.memo
         for direction in ((sb, bb), (bb, sb)):
             rows: List[tuple] = []
             if isinstance(root, LedgerTxnRoot):
-                for kb, entry in root._offers_by_pair(*direction):
+                for kb, blob, entry in root._offer_rows_by_pair(*direction):
                     if kb in overrides:
                         continue
-                    o = entry.data.value
-                    rows.append((Fraction(o.price.n, o.price.d),
-                                 o.offerID, kb))
-                    self._declare_offer(mat, entry)
+                    if blob is None:  # write-ahead overlay: one close's delta
+                        row, keys = _derive_offer(kb, entry)
+                    else:
+                        d = memo.get((kb, blob))
+                        if d is None:
+                            d = _derive_offer(kb, T.LedgerEntry.decode(blob))
+                            memo.put((kb, blob), d)
+                        else:
+                            self.book_rows_reused += 1
+                        row, keys = d
+                    rows.append(row)
+                    mat.keys.update(keys)
             for kb, entry in sorted(overrides.items()):
                 if entry is None:
                     continue
@@ -130,10 +189,12 @@ class PlanContext:
                 if (T.Asset.encode(o.selling),
                         T.Asset.encode(o.buying)) != direction:
                     continue
-                rows.append((Fraction(o.price.n, o.price.d), o.offerID, kb))
-                self._declare_offer(mat, entry)
+                row, keys = _derive_offer(kb, entry)
+                rows.append(row)
+                mat.keys.update(keys)
             rows.sort()
             mat.offers[direction] = rows
+            self.book_rows += len(rows)
         # the pair's liquidity pool (path payments quote it on each hop;
         # the native kernel probes the same key for its decline-if-live
         # pool guard — pair_pool_key_bytes is the one derivation)
@@ -146,26 +207,28 @@ class PlanContext:
         self.books[pair] = mat
         return mat
 
-    def _declare_offer(self, mat: BookMaterialization, entry) -> None:
-        """One resting offer's full reach: the offer itself, its
-        seller's account and trustlines for both legs (crossing settles
-        balances on the seller side), and the offer's sponsor (erasing
-        a consumed offer credits the sponsor's numSponsoring)."""
-        from ..ledger.ledger_txn import entry_to_key
-        from ..transactions import sponsorship as SP
-        from ..transactions import utils as U
 
-        o = entry.data.value
-        seller = o.sellerID.value
-        mat.keys.add(key_bytes(entry_to_key(entry)))
-        mat.keys.add(account_key_bytes(seller))
-        for asset in (o.selling, o.buying):
-            if not U.is_native(asset):
-                mat.keys.add(key_bytes(trustline_key(
-                    seller, U.to_trustline_asset(asset))))
-        sponsor = SP.entry_sponsor(entry)
-        if sponsor is not None:
-            mat.keys.add(account_key_bytes(sponsor))
+def _derive_offer(kb: bytes, entry) -> tuple:
+    """One resting offer's plan-time derivation: its book row and its
+    full reach — the offer itself, its seller's account and trustlines
+    for both legs (crossing settles balances on the seller side), and
+    the offer's sponsor (erasing a consumed offer credits the sponsor's
+    numSponsoring)."""
+    from ..ledger.ledger_txn import entry_to_key
+    from ..transactions import sponsorship as SP
+    from ..transactions import utils as U
+
+    o = entry.data.value
+    seller = o.sellerID.value
+    keys = [key_bytes(entry_to_key(entry)), account_key_bytes(seller)]
+    for asset in (o.selling, o.buying):
+        if not U.is_native(asset):
+            keys.append(key_bytes(trustline_key(
+                seller, U.to_trustline_asset(asset))))
+    sponsor = SP.entry_sponsor(entry)
+    if sponsor is not None:
+        keys.append(account_key_bytes(sponsor))
+    return (Fraction(o.price.n, o.price.d), o.offerID, kb), tuple(keys)
 
 
 def _tl_kb(account_id: bytes, asset) -> Optional[bytes]:

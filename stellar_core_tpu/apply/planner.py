@@ -18,7 +18,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Set, Tuple
 
-from .footprint import PlanContext, TxFootprint, footprint_for
+from .footprint import OfferMemo, PlanContext, TxFootprint, footprint_for
 
 #: conflict token for offer-id-pool allocation (header.idPool)
 IDPOOL_TOKEN = ("header", "idpool")
@@ -79,7 +79,8 @@ class _UnionFind:
                 self.parent[ra] = rb
 
 
-def plan_parallel_apply(apply_order, ltx, allow_single_native: bool = False
+def plan_parallel_apply(apply_order, ltx, allow_single_native: bool = False,
+                        memo: Optional[OfferMemo] = None
                         ) -> Tuple[Optional[ApplyPlan], dict]:
     """Footprint every tx, build the conflict graph, emit clusters.
 
@@ -94,15 +95,22 @@ def plan_parallel_apply(apply_order, ltx, allow_single_native: bool = False
     the native kernel (the adversarial-ring case turns from planner
     refusal into a native fast path; a kernel decline still lands on
     the ordinary sequential loop).
+
+    ``memo``: the owner's ``OfferMemo`` of resting offers' derivations,
+    kept across plans (the plan is the same with or without it);
+    ``stats["book_rows"]`` / ``["book_rows_reused"]`` count the book
+    rows materialized and those it served.
     """
     n = len(apply_order)
-    ctx = PlanContext(ltx)
+    ctx = PlanContext(ltx, memo)
     footprints: List[TxFootprint] = []
     for i, frame in enumerate(apply_order):
         fp = footprint_for(i, frame, ctx)
         if not fp.precise:
             return None, {"txs": n, "clusters": 0,
-                          "unplanned": fp.reason, "tx_index": i}
+                          "unplanned": fp.reason, "tx_index": i,
+                          "book_rows": ctx.book_rows,
+                          "book_rows_reused": ctx.book_rows_reused}
         footprints.append(fp)
 
     uf = _UnionFind(n)
@@ -193,6 +201,8 @@ def plan_parallel_apply(apply_order, ltx, allow_single_native: bool = False
         "conflict_rate": round(1.0 - len(clusters) / n, 4) if n else 0.0,
         "book_pairs": len(ctx.books),
         "kernel_clusters": sum(1 for c in clusters if c.kernel_ok),
+        "book_rows": ctx.book_rows,
+        "book_rows_reused": ctx.book_rows_reused,
     }
     if len(clusters) < 2:
         if allow_single_native and clusters and clusters[0].kernel_ok:

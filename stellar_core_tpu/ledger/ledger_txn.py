@@ -781,12 +781,14 @@ class LedgerTxnRoot(AbstractLedgerTxn):
                 if kb.startswith(prefix) and pend[kb] is not None:
                     yield kb, pend[kb]
 
-    def _offers_by_pair(self, selling: bytes, buying: bytes):
+    def _offer_rows_by_pair(self, selling: bytes, buying: bytes):
         """Every resting offer of one book direction — the parallel-apply
-        planner's order-book materialization (plan-time, main thread).
-        The write-ahead overlay shadows SQL rows; consumers sort the
-        rows themselves, so the appended overlay offers need no order
-        merge."""
+        planner's order-book materialization (plan-time, main thread) —
+        as ``(kb, blob, entry)``: SQL rows come undecoded (``entry``
+        None), so the caller decides whether to decode; write-ahead
+        overlay offers come decoded (``blob`` None) and shadow SQL rows.
+        Consumers sort the rows themselves, so the appended overlay
+        offers need no order merge."""
         pend = self._pending_offers
         for kb, blob in self.db.execute(
                 "SELECT o.key, e.entry FROM offers o "
@@ -794,7 +796,7 @@ class LedgerTxnRoot(AbstractLedgerTxn):
                 "WHERE o.selling = ? AND o.buying = ? "
                 "ORDER BY o.price, o.offerid", (selling, buying)):
             if kb not in pend:
-                yield kb, T.LedgerEntry.decode(blob)
+                yield kb, blob, None
         if pend:
             for kb in sorted(pend):
                 e = pend[kb]
@@ -803,7 +805,7 @@ class LedgerTxnRoot(AbstractLedgerTxn):
                 o = e.data.value
                 if (T.Asset.encode(o.selling) == selling
                         and T.Asset.encode(o.buying) == buying):
-                    yield kb, e
+                    yield kb, None, e
 
     def _offers_by_seller(self, sellerid: bytes):
         pend = self._pending_offers
