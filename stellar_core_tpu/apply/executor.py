@@ -59,19 +59,16 @@ class ApplySnapshot:
     """Immutable pre-apply state shared by every cluster (built on the
     main thread, read-only afterwards — no locking needed)."""
 
-    __slots__ = ("store", "header", "books", "idpool0", "pool_quote")
+    __slots__ = ("store", "header", "books", "idpool0")
 
     def __init__(self, store: Dict[bytes, object], header, books,
-                 idpool0: int, pool_quote: bool = True):
+                 idpool0: int):
         self.store = store
         self.header = header
         # pair token -> {direction (selling, buying): sorted
         #               [(Fraction, offerID, kb)]}
         self.books = books
         self.idpool0 = idpool0
-        # NATIVE_POOL_QUOTE kill switch: False restores the pre-r16
-        # decline-if-live-pool host screen (native_apply._screen_cluster)
-        self.pool_quote = pool_quote
 
 
 def _is_fresh_offer_key(kb: bytes, idpool0: int) -> bool:
@@ -217,49 +214,37 @@ class ParallelApplyManager:
         self.app = app
         cfg = app.config
         self.workers = int(getattr(cfg, "PARALLEL_APPLY_WORKERS", 0) or 0)
-        parallel_on = bool(getattr(cfg, "PARALLEL_APPLY", False))
-        # NATIVE_APPLY=0 is the kernel kill switch: clusters then always
-        # run the Python reference apply.  NATIVE_APPLY_INLINE engages
-        # the planner+kernel WITHOUT a worker pool (workers 0/1): the
-        # kernel is faster even sequentially, and the single-cluster
-        # fast path needs no pool at all.
-        self.native_wanted = bool(getattr(cfg, "NATIVE_APPLY", True))
-        pool_on = parallel_on and self.workers >= 2
-        if self.native_wanted and (pool_on or parallel_on and
-                                   getattr(cfg, "NATIVE_APPLY_INLINE",
-                                           False)):
-            # probe (and build, once per process — the .so is cached)
-            # up front: a host whose kernel cannot build must not pay
-            # the single-cluster planning/snapshot overhead for
-            # guaranteed declines
+        self.enabled = bool(getattr(cfg, "PARALLEL_APPLY", False)) and \
+            self.workers >= 2
+        # kernel-eligible clusters apply through the native kernel
+        # whenever it builds, the Python reference otherwise.  Probed
+        # (and built, once per process — the .so is cached) up front: a
+        # host whose kernel cannot build must not pay the single-cluster
+        # planning/snapshot overhead for guaranteed declines
+        self.native_wanted = False
+        if self.enabled:
             from .native_apply import kernel_module
 
-            if kernel_module() is None:
-                self.native_wanted = False
-        inline_native = parallel_on and self.native_wanted and \
-            bool(getattr(cfg, "NATIVE_APPLY_INLINE", False))
-        self.enabled = pool_on or inline_native
-        # opt-in post-apply invariant pass over kernel-applied cluster
-        # deltas (ROADMAP 2e): configuring INVARIANT_CHECKS engages it,
-        # so chaos runs with INVARIANT_CHECKS=[".*"] cover the native
-        # path too.  Packed deltas decode lazily inside the checkers —
-        # an operator who opts out of checkers never pays the decode.
+            self.native_wanted = kernel_module() is not None
+        # post-apply invariant pass over kernel-applied cluster deltas
+        # (ROADMAP 2e): configuring INVARIANT_CHECKS engages it, so
+        # chaos runs with INVARIANT_CHECKS=[".*"] cover the native path
+        # too.  Packed deltas decode lazily inside the checkers — an
+        # operator who opts out of checkers never pays the decode.
         self.native_invariants = bool(
-            self.enabled and self.native_wanted
+            self.native_wanted
             and getattr(app, "invariants", None) is not None
             and app.invariants.invariants)
-        if (self.enabled and self.native_wanted
-                and getattr(cfg, "INVARIANT_CHECKS", None)):
+        if self.native_wanted and getattr(cfg, "INVARIANT_CHECKS", None):
             from ..utils.logging import get_logger
 
             get_logger("Ledger").info(
                 "native apply kernel on: INVARIANT_CHECKS %s run per-op "
                 "on Python-applied clusters and as a post-apply "
-                "cluster-delta pass on kernel-applied clusters "
-                "(NATIVE_APPLY=0 to run every checker per-op on every "
-                "tx)", cfg.INVARIANT_CHECKS)
+                "cluster-delta pass on kernel-applied clusters",
+                cfg.INVARIANT_CHECKS)
         self.executor = None
-        if pool_on:
+        if self.enabled:
             from concurrent.futures import ThreadPoolExecutor
 
             self.executor = ThreadPoolExecutor(
@@ -379,17 +364,12 @@ class ParallelApplyManager:
         # cluster id — deterministic): a 1000-payment close can plan
         # hundreds of two-tx clusters, and one future per cluster would
         # drown the win in submit/teardown overhead.  A single-cluster
-        # plan (the kernel's adversarial-ring fast path) and the
-        # pool-less native-inline mode run on the close thread instead:
-        # one task's pool round-trip buys nothing.
-        inline = self.executor is None or len(plan.clusters) == 1
-        if inline:
-            groups: List[List] = [list(plan.clusters)]
-        else:
-            n_tasks = min(len(plan.clusters), self.workers * 2)
-            groups = [[] for _ in range(n_tasks)]
-            for cluster in plan.clusters:
-                groups[cluster.cluster_id % n_tasks].append(cluster)
+        # plan (the kernel's adversarial-ring fast path) runs on the
+        # close thread instead: one task's pool round-trip buys nothing.
+        n_tasks = min(len(plan.clusters), self.workers * 2)
+        groups: List[List] = [[] for _ in range(n_tasks)]
+        for cluster in plan.clusters:
+            groups[cluster.cluster_id % n_tasks].append(cluster)
         results: List[Optional[ClusterResult]] = []
         escape: Optional[str] = None
 
@@ -408,11 +388,10 @@ class ParallelApplyManager:
                 escape = escape or f"worker exception: {e!r}"
                 results.append(None)
 
-        if inline:
-            for group in groups:
-                _collect(lambda g=group: self._run_task(
-                    g, snapshot, apply_order, verify, invariant_check,
-                    abort, tracer, parent_token))
+        if n_tasks == 1:
+            _collect(lambda: self._run_task(
+                groups[0], snapshot, apply_order, verify,
+                invariant_check, abort, tracer, parent_token))
         else:
             futures = [self.executor.submit(
                 self._run_task, group, snapshot, apply_order, verify,
@@ -523,10 +502,7 @@ class ParallelApplyManager:
         books = {pair: mat.offers
                  for pair, mat in plan.context.books.items()}
         header = ltx.header()
-        pool_quote = bool(getattr(self.app.config, "NATIVE_POOL_QUOTE",
-                                  True))
-        return ApplySnapshot(store, header, books, header.idPool,
-                             pool_quote)
+        return ApplySnapshot(store, header, books, header.idPool)
 
     def _run_task(self, clusters, snapshot, apply_order, verify,
                   invariant_check, abort, tracer,

@@ -17,8 +17,7 @@ and PATH_PAYMENT strict-send/strict-receive over declared hop pairs
 (per-hop pool descriptors ride the shape so the kernel can quote a
 LIVE constant-product pool on the hop in-kernel — book-vs-pool
 arbitration mirrors ``convert_with_offers_and_pools``; pool
-deposit/withdraw stay host-side, and ``NATIVE_POOL_QUOTE=0`` restores
-the old decline-if-live behavior via a host screen).
+deposit/withdraw stay host-side).
 
 Beyond per-cluster apply, ``run_fee_phase_native`` batches the whole
 fee/seqnum phase of a close into ONE GIL-released ``charge_fees``
@@ -216,8 +215,7 @@ def _path_hops(chain) -> tuple:
     """The effective conversion steps of a path-payment chain: adjacent
     equal assets collapse (exactly the reference's ``assets_equal``
     skip), and each hop carries its pair's liquidity-pool key so the
-    kernel can quote a LIVE pool against a DECLARED key (and the
-    ``NATIVE_POOL_QUOTE=0`` host screen can probe the same key)."""
+    kernel can quote a LIVE pool against a DECLARED key."""
     from ..transactions import liquidity_pool as LP
     from ..transactions import utils as U
 
@@ -297,17 +295,6 @@ def _screen_cluster(cluster, snapshot, apply_order, verify):
             # destination accounts are touched by every payment-shaped
             # apply; screen their persistent unsupported shapes too
             _screen_account(snapshot, shape[1], idx)
-        if shape[0] == "pathpay" and not getattr(snapshot, "pool_quote",
-                                                 True):
-            # NATIVE_POOL_QUOTE=0 kill switch: restore the pre-r16
-            # decline-if-live-pool behavior so the Python reference
-            # adjudicates every pool-backed hop
-            for _, _, pool_kb in shape[7]:
-                if snapshot.store.get(pool_kb) is not None:
-                    raise KernelDecline(
-                        f"tx {idx}: liquidity pool on hop "
-                        f"(pool quoting off)", op="pathpay",
-                        code="liquidity_pool_on_hop")
     return frames
 
 

@@ -258,8 +258,8 @@ class LedgerManager:
 
             # phase 1: fees + seqnums for every tx, in apply order
             # (ref processFeesSeqNums :1164) — one batched GIL-released
-            # kernel call when every tx fits (NATIVE_FEE), else the
-            # per-tx reference loop; bytes identical either way
+            # kernel call when the kernel is built and every tx fits,
+            # else the per-tx reference loop; bytes identical either way
             base_fee = prev_header.baseFee
             with tracer.span("ledger.close.fee") as sp, \
                     self.metrics.timer(
@@ -719,22 +719,18 @@ class LedgerManager:
         against its source account, in apply order.
 
         One batched GIL-released kernel call covers the whole set when
-        NATIVE_FEE (and the kernel itself) is on and every source
-        account has a kernel-supported shape — the kernel returns the
-        per-tx ``feeProcessing`` LedgerEntryChanges pre-encoded, bit-
-        identical to the reference loop's.  Any tx the kernel can't
-        charge declines the WHOLE batch (fees are strictly sequential:
-        a repeat source must see the prior tx's post-image) and the
-        per-tx reference loop below takes over.  NATIVE_FEE=0 is the
-        kill switch: skip the kernel silently, no decline counters —
-        off is not a coverage gap."""
+        the kernel is built and every source account has a kernel-
+        supported shape — the kernel returns the per-tx
+        ``feeProcessing`` LedgerEntryChanges pre-encoded, bit-identical
+        to the reference loop's.  Otherwise the kernel declines the
+        WHOLE batch (fees are strictly sequential: a repeat source must
+        see the prior tx's post-image) and the per-tx reference loop
+        below takes over."""
         from ..utils import tracing
 
         metrics = self.metrics
-        cfg = self.app.config
         col = tracing.op_collector()
-        if (apply_order and getattr(cfg, "NATIVE_FEE", True)
-                and getattr(cfg, "NATIVE_APPLY", True)):
+        if apply_order:
             from ..apply import native_apply as NA
 
             with tracing.stopwatch() as sw:
@@ -773,8 +769,8 @@ class LedgerManager:
         parallel executor pre-encoded on worker threads (overlapping the
         GIL-free native serialization with other clusters' apply); when
         absent, encode here: one batched native crossing that releases
-        the GIL for the copy-out (NATIVE_TAIL_ENCODE), else the per-row
-        reference loop — bytes identical either way."""
+        the GIL for the copy-out, else the per-row reference loop —
+        bytes identical either way."""
         cur = self.app.database.cursor()
         if encoded_rows is None:
             encoded_rows = self._encode_commit_rows(frames, metas)
@@ -791,22 +787,19 @@ class LedgerManager:
         through ONE native xdrpack call whose copy-out phase runs with
         the GIL released (``pack_many``) — on the pipelined tail worker
         that overlap is concurrent with ledger N+1's close.  Falls back
-        to the per-row reference encode when NATIVE_TAIL_ENCODE=0 or
-        the native packer is unavailable."""
-        if getattr(self.app.config, "NATIVE_TAIL_ENCODE", True):
-            from ..xdr import runtime
+        to the per-row reference encode when the native packer is
+        unavailable."""
+        from ..xdr import runtime
 
-            pairs = []
-            for frame, meta in zip(frames, metas):
-                pairs.append((T.TransactionEnvelope, frame.envelope))
-                pairs.append((T.TransactionResultPair, meta.result))
-                pairs.append((T.TransactionMeta, meta.txApplyProcessing))
-            flat = runtime.encode_many(pairs)
-            if flat is not None:
-                self.metrics.counter("apply.native.tail_encode.hit")\
-                    .inc()
-                return [tuple(flat[i:i + 3])
-                        for i in range(0, len(flat), 3)]
+        pairs = []
+        for frame, meta in zip(frames, metas):
+            pairs.append((T.TransactionEnvelope, frame.envelope))
+            pairs.append((T.TransactionResultPair, meta.result))
+            pairs.append((T.TransactionMeta, meta.txApplyProcessing))
+        flat = runtime.encode_many(pairs)
+        if flat is not None:
+            self.metrics.counter("apply.native.tail_encode.hit").inc()
+            return [tuple(flat[i:i + 3]) for i in range(0, len(flat), 3)]
         return [(T.TransactionEnvelope.encode(frame.envelope),
                  T.TransactionResultPair.encode(meta.result),
                  T.TransactionMeta.encode(meta.txApplyProcessing))

@@ -195,8 +195,10 @@ class Config:
 
         # parallel transaction apply (stellar_core_tpu/apply/): footprint
         # planner + conflict-cluster scheduler + bit-identical concurrent
-        # executor.  PARALLEL_APPLY is the kill switch (env
-        # PARALLEL_APPLY=0 also disables); WORKERS <= 1 disables too.
+        # executor.  Kernel-eligible clusters apply through the native
+        # kernel (native/apply_kernel.cpp) whenever it builds, the
+        # Python reference otherwise.  PARALLEL_APPLY is the kill switch
+        # (env PARALLEL_APPLY=0 also disables); WORKERS <= 1 disables too.
         # Env reads live HERE on purpose: main/ is outside detlint's
         # consensus det-wallclock scope, and the env only gates WHETHER
         # the parallel path runs — results are bit-identical either way.
@@ -208,45 +210,6 @@ class Config:
         self.PARALLEL_APPLY_WORKERS: int = kw.get(
             "PARALLEL_APPLY_WORKERS",
             int(_os.environ.get("PARALLEL_APPLY_WORKERS", "2") or 0))
-        # native GIL-free apply kernel (native/apply_kernel.cpp) for
-        # kernel-eligible clusters; NATIVE_APPLY=0 is the kill switch —
-        # every cluster then runs the Python reference apply
-        # (bit-identical either way, enforced by test_native_apply.py).
-        # Note: INVARIANT_CHECKS run per-op on Python-applied clusters
-        # only; kernel-applied clusters rely on the kernel's own
-        # exact-shape parse + bounds guards (set NATIVE_APPLY=0 to run
-        # every configured checker on every tx).
-        self.NATIVE_APPLY: bool = kw.get(
-            "NATIVE_APPLY",
-            _os.environ.get("NATIVE_APPLY", "1") != "0")
-        # engage the planner+kernel WITHOUT a worker pool (workers 0/1):
-        # the kernel beats Python even applying clusters sequentially on
-        # the close thread.  Off by default so workers=0 keeps meaning
-        # "plain sequential apply" unless explicitly opted in.
-        self.NATIVE_APPLY_INLINE: bool = kw.get(
-            "NATIVE_APPLY_INLINE",
-            _os.environ.get("NATIVE_APPLY_INLINE", "0") == "1")
-        # batched fee/seqnum phase (apply_kernel.cpp charge_fees): one
-        # GIL-released call replaces the per-tx process_fee_seq_num
-        # loop.  NATIVE_FEE=0 is the kill switch; bytes are identical
-        # either way (tests/test_native_fee.py).  Follows NATIVE_APPLY:
-        # the fee batch never engages with the apply kernel killed.
-        self.NATIVE_FEE: bool = kw.get(
-            "NATIVE_FEE",
-            _os.environ.get("NATIVE_FEE", "1") != "0")
-        # in-kernel constant-product pool quoting on path-payment hops;
-        # NATIVE_POOL_QUOTE=0 restores the decline-if-live-pool host
-        # screen (pool hops then always run the Python reference).
-        self.NATIVE_POOL_QUOTE: bool = kw.get(
-            "NATIVE_POOL_QUOTE",
-            _os.environ.get("NATIVE_POOL_QUOTE", "1") != "0")
-        # native tail encode (xdr_pack.c pack_many): the commit tail's
-        # tx-history row encodes collapse into one native crossing.
-        # NATIVE_TAIL_ENCODE=0 falls back to per-value encode() — same
-        # packer, same bytes.
-        self.NATIVE_TAIL_ENCODE: bool = kw.get(
-            "NATIVE_TAIL_ENCODE",
-            _os.environ.get("NATIVE_TAIL_ENCODE", "1") != "0")
         # one JSON line of session apply stats appended at shutdown —
         # tools/verify_green.py's parallel smoke aggregates these to
         # report aborts observed across the suite

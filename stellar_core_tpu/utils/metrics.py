@@ -183,10 +183,14 @@ class MetricsRegistry:
         # close's tail worker and gc callbacks both register lazily):
         # without the lock, two threads racing the get-then-insert
         # below could each create the metric and one would silently
-        # lose its updates.  Reads stay lock-free: iteration always
+        # lose its updates.  Reentrant: a collection triggered by the
+        # allocation inside _get runs the vitals gc callback on the
+        # thread that holds the lock, and that callback registers its
+        # own metrics lazily.  Reads stay lock-free: iteration always
         # goes through sorted(...) whose list materialization is
         # GIL-atomic.
-        self._reg_lock = register_lock(threading.Lock(), "metrics.registry")
+        self._reg_lock = register_lock(threading.RLock(),
+                                       "metrics.registry")
 
     def _get(self, name: str, cls, *args):
         m = self._metrics.get(name)

@@ -124,6 +124,39 @@ def test_metrics_registry():
         reg.meter("ledger.ledger.count")  # type clash
 
 
+def test_metrics_registry_reentrant_from_gc_callback():
+    """A collection inside a registration runs the gc callbacks on the
+    thread that holds the registry lock; one that registers a metric
+    there (the vitals gc-pause histogram) must not deadlock."""
+    import gc
+    import threading
+
+    from stellar_core_tpu.utils.metrics import Gauge
+
+    reg = MetricsRegistry()
+
+    class CollectingGauge(Gauge):
+        def __init__(self):
+            super().__init__()
+            gc.collect()
+
+    def on_gc(phase, info):
+        if phase == "stop":
+            reg.histogram("test.gc.pause")
+
+    gc.callbacks.append(on_gc)
+    try:
+        t = threading.Thread(target=reg._get,
+                             args=("test.gauge", CollectingGauge),
+                             daemon=True)
+        t.start()
+        t.join(10)
+    finally:
+        gc.callbacks.remove(on_gc)
+    assert not t.is_alive(), "metrics registry deadlocked in gc callback"
+    assert "test.gc.pause" in reg._metrics
+
+
 # -- work system ------------------------------------------------------------
 
 

@@ -1,12 +1,14 @@
 """Native GIL-free apply kernel (ISSUE 6 tentpole): bit-identity of
 kernel-applied clusters against the Python reference apply, the
 decline-to-Python fallback, the packed-delta merge tier, and the
-NATIVE_APPLY kill switch.
+reference path of a host on which the kernel did not build
+(``txtest.native_unbuilt``).
 
 The consensus property: for ANY tx set, closes with the native kernel
 engaged must produce byte-identical ledger header hash, bucket-list
-hash and tx meta versus forced-Python apply — across worker counts
-(0 inline / 2 / 4), and across PYTHONHASHSEED values (subprocess).
+hash and tx meta versus the Python reference apply — across worker
+counts (0 sequential / 2 / 4), and across PYTHONHASHSEED values
+(subprocess).
 A kernel-ineligible tx inside an otherwise-eligible set must route its
 cluster (and only its cluster) through the Python path and STILL
 match.
@@ -15,6 +17,7 @@ import hashlib
 import os
 import subprocess
 import sys
+import threading
 
 import pytest
 
@@ -26,6 +29,7 @@ from stellar_core_tpu.xdr import types as T
 from .test_parallel_apply import (
     _assert_identical, _close_and_fingerprint, _run_workload,
 )
+from .txtest import native_unbuilt
 
 
 def test_kernel_builds():
@@ -35,16 +39,17 @@ def test_kernel_builds():
         "apply kernel failed to build (g++ is baked into the image)"
 
 
-# -- the bit-identity property (native vs forced-Python) ---------------------
+# -- the bit-identity property (native vs Python reference) ------------------
 
 def test_native_matches_python_across_worker_counts():
-    """Randomized pay/mixed/crossing workload: kernel on vs
-    NATIVE_APPLY=0 at workers 2 and 4 — identical fingerprints, and the
-    kernel must actually engage (hits > 0, declines accounted)."""
-    python_fps, python_stats = _run_workload(2, NATIVE_APPLY=False)
+    """Randomized pay/mixed/crossing workload: kernel on vs a host
+    without the kernel at workers 2 and 4 — identical fingerprints, and
+    the kernel must actually engage (hits > 0, declines accounted)."""
+    with native_unbuilt():
+        python_fps, python_stats = _run_workload(2)
     assert python_stats["native_hits"] == 0
     for workers in (2, 4):
-        fps, stats = _run_workload(workers, NATIVE_APPLY=True)
+        fps, stats = _run_workload(workers)
         _assert_identical(python_fps, fps, f"native workers={workers}")
         assert stats["native_hits"] > 0, \
             f"kernel never engaged at workers={workers}: {stats}"
@@ -52,22 +57,35 @@ def test_native_matches_python_across_worker_counts():
 
 
 def test_native_inline_workers0_matches_sequential():
-    """NATIVE_APPLY_INLINE engages planner+kernel with NO worker pool:
-    clusters apply natively on the close thread, sequentially — faster
-    payment strips without a single thread hop, same bytes."""
-    seq, seq_stats = _run_workload(0, n_closes=3)
-    assert seq_stats["parallel_closes"] == 0
-    fps, stats = _run_workload(0, n_closes=3, NATIVE_APPLY=True,
-                               NATIVE_APPLY_INLINE=True)
-    _assert_identical(seq, fps, "inline native")
-    assert stats["parallel_closes"] > 0, stats
+    """A one-cluster plan runs on the close thread under the worker
+    pool: the path-payment closes are one book-pair cluster, applied
+    through the kernel with no pool round trip — same bytes as the
+    sequential close (workers 0)."""
+    seq, _, _ = _pathpay_workload(0, n_closes=2)
+    tasks = []
+
+    def spy(app):
+        mgr = app.parallel_apply
+        run_task = mgr._run_task
+
+        def recording(clusters, *args):
+            tasks.append((len(clusters), threading.current_thread()))
+            return run_task(clusters, *args)
+        mgr._run_task = recording
+
+    fps, stats, _ = _pathpay_workload(2, n_closes=2, app_hook=spy)
+    _assert_identical(seq, fps, "one-cluster plan on the close thread")
     assert stats["native_hits"] > 0, stats
+    single = [t for n, t in tasks if n == 1]
+    assert single, tasks
+    assert all(t is threading.main_thread() for t in single), tasks
 
 
 def test_kill_switch_restores_pure_python_path():
     seq, _ = _run_workload(0, n_closes=2)
-    fps, stats = _run_workload(2, n_closes=2, NATIVE_APPLY=False)
-    _assert_identical(seq, fps, "NATIVE_APPLY=0")
+    with native_unbuilt():
+        fps, stats = _run_workload(2, n_closes=2)
+    _assert_identical(seq, fps, "kernel not built")
     assert stats["native_hits"] == 0
     assert stats["native_declines"] == 0
 
@@ -75,11 +93,6 @@ def test_kill_switch_restores_pure_python_path():
 # -- decline paths -----------------------------------------------------------
 
 def _mk_app(workers, **kw):
-    # these tests are ABOUT the kernel: force it on via config so the
-    # suite stays meaningful (and green) under verify_green's
-    # NATIVE_APPLY=0 fallback-smoke environment — the Python arms
-    # always pass NATIVE_APPLY=False explicitly
-    kw.setdefault("NATIVE_APPLY", True)
     app = Application(VirtualClock(ClockMode.VIRTUAL_TIME), test_config(
         TESTING_UPGRADE_MAX_TX_SET_SIZE=300,
         PARALLEL_APPLY_WORKERS=workers, **kw))
@@ -156,14 +169,14 @@ def test_ineligible_tx_mid_cluster_falls_back_and_matches():
     assert stats["aborts"] == 0, stats
 
 
-def _extra_signer_workload(workers, app_hook=None, **kw):
+def _extra_signer_workload(workers, app_hook=None):
     """State-level decline: an account grows a second signer, so later
     payments from it are kernel-SHAPED but the kernel's account parse
     refuses (signers stay host-side) — decline, Python fallback, same
     bytes."""
     from stellar_core_tpu.crypto import sha256
 
-    app = _mk_app(workers, **kw)
+    app = _mk_app(workers)
     if app_hook is not None:
         app_hook(app)
     lg = LoadGenerator(app)
@@ -215,8 +228,7 @@ def test_single_cluster_ring_goes_native_inline():
     planner refusal; with the kernel it becomes a single-cluster native
     plan applied inline on the close thread."""
     seq, _ = _run_workload(0, pattern="ring", n_closes=2)
-    fps, stats = _run_workload(2, pattern="ring", n_closes=2,
-                               NATIVE_APPLY=True)
+    fps, stats = _run_workload(2, pattern="ring", n_closes=2)
     _assert_identical(seq, fps, "ring native")
     assert stats["native_hits"] > 0, stats
 
@@ -229,10 +241,10 @@ def _hit_rate(stats) -> float:
     return stats["native_hits"] / clusters if clusters else 0.0
 
 
-def _credit_workload(workers, n_closes=3, txs=60, **kw):
+def _credit_workload(workers, n_closes=3, txs=60):
     """Credit-asset payments over disjoint pairs + changeTrust
     create/update salt — the credit-heavy shape real traffic has."""
-    app = _mk_app(workers, **kw)
+    app = _mk_app(workers)
     lg = LoadGenerator(app)
     lg.create_accounts(40)
     lg.setup_credit()
@@ -250,7 +262,8 @@ def _credit_workload(workers, n_closes=3, txs=60, **kw):
 
 
 def test_credit_mix_goes_native_and_matches():
-    seq, _, _ = _credit_workload(0, NATIVE_APPLY=False)
+    with native_unbuilt():
+        seq, _, _ = _credit_workload(0)
     fps, stats, hits = _credit_workload(2)
     _assert_identical(seq, fps, "credit mix")
     assert stats["aborts"] == 0, stats
@@ -264,8 +277,8 @@ def test_credit_mix_goes_native_and_matches():
 def test_changetrust_delete_goes_native_and_matches():
     """Trustline create (close 1) then delete via limit=0 (close 2) —
     the subentry-reserve round trip, in-kernel both ways."""
-    def run(workers, native):
-        app = _mk_app(workers, NATIVE_APPLY=native)
+    def run(workers):
+        app = _mk_app(workers)
         lg = LoadGenerator(app)
         lg.create_accounts(10)
         lg.setup_credit()
@@ -280,15 +293,18 @@ def test_changetrust_delete_goes_native_and_matches():
         app.graceful_stop()
         return fps, stats
 
-    seq, _ = run(0, False)
-    fps, stats = run(2, True)
+    with native_unbuilt():
+        seq, _ = run(0)
+    fps, stats = run(2)
     _assert_identical(seq, fps, "changetrust delete")
     assert stats["native_hits"] > 0, stats
     assert stats["native_declines"] == 0, stats
 
 
-def _pathpay_workload(workers, hops=2, n_closes=3, txs=40, **kw):
-    app = _mk_app(workers, **kw)
+def _pathpay_workload(workers, hops=2, n_closes=3, txs=40, app_hook=None):
+    app = _mk_app(workers)
+    if app_hook is not None:
+        app_hook(app)
     lg = LoadGenerator(app)
     lg.create_accounts(24)
     maker_envs = lg.setup_path(hops=hops, makers=4)
@@ -311,8 +327,9 @@ def _pathpay_workload(workers, hops=2, n_closes=3, txs=40, **kw):
 def test_path_payments_go_native_and_match():
     """2-hop strict-send + strict-receive chains over seeded books:
     the whole close is one book-pair cluster, applied natively inline;
-    bytes identical to forced-Python."""
-    seq, _, _ = _pathpay_workload(0, NATIVE_APPLY=False)
+    bytes identical to the Python reference."""
+    with native_unbuilt():
+        seq, _, _ = _pathpay_workload(0)
     fps, stats, hits = _pathpay_workload(2)
     _assert_identical(seq, fps, "path payments")
     assert stats["aborts"] == 0, stats
@@ -321,8 +338,8 @@ def test_path_payments_go_native_and_match():
 
 
 def test_three_hop_path_payments_match():
-    seq, _, _ = _pathpay_workload(0, hops=3, n_closes=2,
-                                  NATIVE_APPLY=False)
+    with native_unbuilt():
+        seq, _, _ = _pathpay_workload(0, hops=3, n_closes=2)
     fps, stats, _ = _pathpay_workload(2, hops=3, n_closes=2)
     _assert_identical(seq, fps, "3-hop path payments")
     assert stats["native_hits"] > 0, stats
@@ -331,9 +348,8 @@ def test_three_hop_path_payments_match():
 def test_live_pool_on_hop_goes_native_and_matches():
     """A LIVE liquidity pool on a hop pair quotes IN-KERNEL (r16):
     the constant-product-vs-book arbitration runs inside the crossing
-    loop, bytes identical to the Python reference.  NATIVE_POOL_QUOTE=0
-    is the kill switch — the old decline-if-live-pool screen returns,
-    Python adjudicates, same bytes, taxonomy names the guard."""
+    loop, bytes identical to the Python reference — at workers 0, and
+    at workers 2 on a host without the kernel."""
     from stellar_core_tpu.ledger.ledger_txn import LedgerTxn
     from stellar_core_tpu.transactions import liquidity_pool as LP
     from stellar_core_tpu.transactions import utils as U
@@ -363,8 +379,8 @@ def test_live_pool_on_hop_goes_native_and_matches():
             ltx.put(U.wrap_entry(T.LedgerEntryType.LIQUIDITY_POOL, lp))
             ltx.commit()
 
-    def run(workers, native, **kw):
-        app = _mk_app(workers, NATIVE_APPLY=native, **kw)
+    def run(workers):
+        app = _mk_app(workers)
         lg = LoadGenerator(app)
         lg.create_accounts(12)
         maker_envs = lg.setup_path(hops=2, makers=2)
@@ -382,19 +398,18 @@ def test_live_pool_on_hop_goes_native_and_matches():
         app.graceful_stop()
         return fps, stats
 
-    seq, _ = run(0, False)
-    fps, stats = run(2, True)
+    with native_unbuilt():
+        seq, _ = run(0)
+    fps, stats = run(2)
     _assert_identical(seq, fps, "pool-on-hop native")
     assert stats["native_hits"] > 0, stats
-    # kill switch: with pool quoting forced off the old host screen
-    # declines the cluster and the Python reference adjudicates —
-    # bytes still identical
-    fps_off, stats_off = run(2, True, NATIVE_POOL_QUOTE=False)
-    _assert_identical(seq, fps_off, "pool-on-hop decline (quote off)")
-    assert stats_off["native_declines"] > 0, stats_off
-    assert any("liquidity pool on hop" in r
-               for r in stats_off["native_decline_reasons"]), \
-        stats_off["native_decline_reasons"]
+    # the same pool hops at workers 2 on a host without the kernel:
+    # the Python reference adjudicates every hop — bytes still identical
+    with native_unbuilt():
+        fps_off, stats_off = run(2)
+    _assert_identical(seq, fps_off, "pool-on-hop Python reference")
+    assert stats_off["native_hits"] == 0, stats_off
+    assert stats_off["native_declines"] == 0, stats_off
 
 
 def test_offer_modify_delete_go_native_and_match():
@@ -403,8 +418,8 @@ def test_offer_modify_delete_go_native_and_match():
     snapshot, old liabilities release, the crossing loop re-runs."""
     from stellar_core_tpu.ledger.ledger_txn import LedgerTxn
 
-    def run(workers, native):
-        app = _mk_app(workers, NATIVE_APPLY=native)
+    def run(workers):
+        app = _mk_app(workers)
         lg = LoadGenerator(app)
         lg.payment_pattern = "pairs"
         lg.create_accounts(12)
@@ -447,8 +462,9 @@ def test_offer_modify_delete_go_native_and_match():
         app.graceful_stop()
         return fps, stats
 
-    seq, _ = run(0, False)
-    fps, stats = run(2, True)
+    with native_unbuilt():
+        seq, _ = run(0)
+    fps, stats = run(2)
     _assert_identical(seq, fps, "offer modify/delete")
     assert stats["native_hits"] > 0, stats
     assert stats["native_declines"] == 0, stats
@@ -628,8 +644,14 @@ import sys
 
 sys.path.insert(0, {repo!r})
 from tests.test_apply_determinism import _run_mixed_workload
+from tests.txtest import native_unbuilt
 
-for lh, bh, meta in _run_mixed_workload():
+if {native}:
+    fps = _run_mixed_workload()
+else:
+    with native_unbuilt():
+        fps = _run_mixed_workload()
+for lh, bh, meta in fps:
     print(lh.hex(), bh.hex(), hashlib.sha256(meta).hexdigest())
 """
 
@@ -637,18 +659,18 @@ for lh, bh, meta in _run_mixed_workload():
 @pytest.mark.slow
 def test_native_close_bit_identical_under_hashseed_variation():
     """Mixed workload with the kernel engaged under PYTHONHASHSEED 0 vs
-    4242, cross-checked against a forced-Python run: all three must
-    produce the same per-close fingerprints."""
+    4242, cross-checked against a run on a host without the kernel: all
+    three must produce the same per-close fingerprints."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     outputs = []
-    for seed, native in (("0", "1"), ("4242", "1"), ("0", "0")):
+    for seed, native in (("0", True), ("4242", True), ("0", False)):
         env = dict(os.environ)
         env["PYTHONHASHSEED"] = seed
         env["JAX_PLATFORMS"] = "cpu"
         env["PARALLEL_APPLY_WORKERS"] = "2"
-        env["NATIVE_APPLY"] = native
         proc = subprocess.run(
-            [sys.executable, "-c", _HASHSEED_WORKER.format(repo=repo)],
+            [sys.executable, "-c", _HASHSEED_WORKER.format(
+                repo=repo, native=native)],
             capture_output=True, text=True, cwd=repo, env=env,
             timeout=600)
         assert proc.returncode == 0, proc.stderr[-4000:]
@@ -658,7 +680,7 @@ def test_native_close_bit_identical_under_hashseed_variation():
     assert outputs[0] == outputs[1], \
         "native close fingerprints diverged across hash seeds"
     assert outputs[0] == outputs[2], \
-        "native close fingerprints diverged from forced-Python apply"
+        "native close fingerprints diverged from the Python reference"
 
 
 # -- detlint scope (satellite) -----------------------------------------------
@@ -700,7 +722,8 @@ def test_native_cluster_invariant_violation_aborts_to_python():
     """A violation seen ONLY at cluster granularity (frame is None —
     modeling a kernel-side divergence the per-op Python path does not
     reproduce) must abort the parallel attempt; the sequential replay's
-    bytes win and the close completes bit-identical to forced-Python."""
+    bytes win and the close completes bit-identical to the Python
+    reference."""
     from stellar_core_tpu.invariant.manager import Invariant
 
     class NativeOnlyTrip(Invariant):
@@ -709,15 +732,15 @@ def test_native_cluster_invariant_violation_aborts_to_python():
         def check_on_tx_apply(self, ltx, frame, ok):
             return "tripped on a kernel delta" if frame is None else ""
 
-    seq, _ = _run_workload(2, n_closes=2, NATIVE_APPLY=False)
+    with native_unbuilt():
+        seq, _ = _run_workload(2, n_closes=2)
     apps = []
 
     def arm(app):
         app.invariants.invariants.append(NativeOnlyTrip())
         apps.append(app)
 
-    fps, stats = _run_workload(2, n_closes=2, NATIVE_APPLY=True,
-                               app_hook=arm)
+    fps, stats = _run_workload(2, n_closes=2, app_hook=arm)
     _assert_identical(seq, fps, "native invariant abort")
     assert stats["aborts"] > 0, stats
     assert apps[0].metrics.counter(

@@ -4,12 +4,13 @@ fee/seqnum phase, in-kernel pool quoting, and the native tail encode.
 The consensus property is the same one every native crossing carries:
 for ANY tx set, closes with each r16 feature engaged must produce
 byte-identical ledger header hash, bucket-list hash and tx meta versus
-that feature forced off (``NATIVE_FEE=0`` / ``NATIVE_POOL_QUOTE=0`` /
-``NATIVE_TAIL_ENCODE=0``), across worker counts (0 inline / 2 / 4) and
-across PYTHONHASHSEED values (subprocess arms).  A fee batch the kernel
-cannot charge (any unsupported source-account shape) must decline the
-WHOLE batch — fee charging is strictly sequential, a repeat source has
-to see the prior tx's post-image — and still match bytes.
+the Python reference a host without the native code runs
+(``txtest.native_unbuilt``), across worker counts (0 sequential / 2 /
+4) and across PYTHONHASHSEED values (subprocess arms).  A fee batch the
+kernel cannot charge (any unsupported source-account shape) must
+decline the WHOLE batch — fee charging is strictly sequential, a
+repeat source has to see the prior tx's post-image — and still match
+bytes.
 """
 import os
 import subprocess
@@ -24,6 +25,7 @@ from stellar_core_tpu.xdr import types as T
 from .test_parallel_apply import (
     _assert_identical, _close_and_fingerprint, _run_workload,
 )
+from .txtest import native_unbuilt
 
 
 def _fee_metrics(app):
@@ -40,14 +42,17 @@ def _capture(box):
 # -- fee phase in-kernel -----------------------------------------------------
 
 def test_fee_batch_on_off_bit_identical_across_worker_counts():
-    """Mixed pay/DEX workload, batched fee kernel vs NATIVE_FEE=0, at
-    workers 0/2/4 — identical fingerprints, and the fee batch actually
-    engages (hit > 0, no declines on the clean workload)."""
-    base, _ = _run_workload(0, NATIVE_APPLY=False, NATIVE_FEE=False)
+    """Mixed pay/DEX workload, batched fee kernel vs the per-tx
+    reference loop, at workers 0/2/4 — identical fingerprints, and the
+    fee batch actually engages (hit > 0, no declines on the clean
+    workload)."""
+    base_box = {}
+    with native_unbuilt():
+        base, _ = _run_workload(0, app_hook=_capture(base_box))
+    assert _fee_metrics(base_box["app"]).get("apply.native.fee.hit", 0) == 0
     for workers in (0, 2, 4):
         box = {}
-        fps, _ = _run_workload(workers, NATIVE_APPLY=True,
-                               app_hook=_capture(box))
+        fps, _ = _run_workload(workers, app_hook=_capture(box))
         _assert_identical(base, fps, f"fee batch workers={workers}")
         mets = _fee_metrics(box["app"])
         assert mets.get("apply.native.fee.hit", 0) > 0, \
@@ -61,11 +66,10 @@ def test_fee_batch_repeat_sources_see_running_balance():
     bumped seqnum, accumulated feePool) — the reason the batch is
     all-or-nothing.  A different seed than the worker-count sweep keeps
     the coverage independent."""
-    base, _ = _run_workload(0, seed=23, n_closes=3,
-                            NATIVE_APPLY=False, NATIVE_FEE=False)
+    with native_unbuilt():
+        base, _ = _run_workload(0, seed=23, n_closes=3)
     box = {}
-    fps, _ = _run_workload(0, seed=23, n_closes=3, NATIVE_APPLY=True,
-                           app_hook=_capture(box))
+    fps, _ = _run_workload(0, seed=23, n_closes=3, app_hook=_capture(box))
     _assert_identical(base, fps, "repeat-source fee batch")
     mets = _fee_metrics(box["app"])
     assert mets.get("apply.native.fee.hit", 0) > 0, mets
@@ -78,8 +82,8 @@ def test_unsupported_account_declines_whole_fee_batch_and_matches():
     account-shape guard."""
     from .test_native_apply import _extra_signer_workload
 
-    base, _ = _extra_signer_workload(0, NATIVE_APPLY=False,
-                                     NATIVE_FEE=False)
+    with native_unbuilt():
+        base, _ = _extra_signer_workload(0)
     box = {}
     fps, _ = _extra_signer_workload(2, app_hook=_capture(box))
     _assert_identical(base, fps, "fee batch whole-batch decline")
@@ -92,17 +96,13 @@ def test_unsupported_account_declines_whole_fee_batch_and_matches():
 
 # -- pool quoting in-kernel --------------------------------------------------
 
-def _pool_workload(workers, n_closes=2, txs=30, app_hook=None, **kw):
+def _pool_workload(workers, n_closes=2, txs=30, app_hook=None):
     """payment_pattern="pool": every tx is a path payment whose hops
     cross LIVE constant-product pools (no maker books — the empty book
     loses arbitration, so the pool is the venue)."""
-    kw.setdefault("NATIVE_APPLY", True)
-    if workers == 0 and kw["NATIVE_APPLY"]:
-        # no worker pool: the kernel engages via the inline native path
-        kw.setdefault("NATIVE_APPLY_INLINE", True)
     app = Application(VirtualClock(ClockMode.VIRTUAL_TIME), test_config(
         TESTING_UPGRADE_MAX_TX_SET_SIZE=300,
-        PARALLEL_APPLY_WORKERS=workers, **kw))
+        PARALLEL_APPLY_WORKERS=workers))
     app.start()
     if app_hook is not None:
         app_hook(app)
@@ -123,14 +123,20 @@ def _pool_workload(workers, n_closes=2, txs=30, app_hook=None, **kw):
 
 def test_pool_workload_native_across_worker_counts():
     """The r16 coverage-cliff fix: a live pool on a hop no longer
-    declines the cluster.  Kernel quotes it, matches forced-Python at
-    workers 0/2/4, and the native hit rate stays clean (no declines)."""
-    base, base_stats, _ = _pool_workload(0, NATIVE_APPLY=False)
+    declines the cluster.  Kernel quotes it at workers 2/4 and matches
+    the Python reference, as does the sequential close (workers 0) with
+    the fee kernel; the native hit rate stays clean (no declines)."""
+    with native_unbuilt():
+        base, base_stats, _ = _pool_workload(0)
     assert base_stats["native_hits"] == 0
     for workers in (0, 2, 4):
-        fps, stats, _ = _pool_workload(workers)
+        box = {}
+        fps, stats, _ = _pool_workload(workers, app_hook=_capture(box))
         _assert_identical(base, fps, f"pool workers={workers}")
-        assert stats["native_hits"] > 0, (workers, stats)
+        mets = _fee_metrics(box["app"])
+        assert mets.get("apply.native.fee.hit", 0) > 0, (workers, mets)
+        if workers:
+            assert stats["native_hits"] > 0, (workers, stats)
         assert stats["native_declines"] == 0, (workers, stats)
 
 
@@ -170,23 +176,24 @@ def test_tail_encode_on_off_bit_identical():
     ``encoded_rows is None`` and the commit tail still encodes per-row):
     one batched ``pack_many`` crossing vs the per-row Python loop,
     identical bytes, and the batch actually engages."""
-    base, _ = _run_workload(0, NATIVE_APPLY=False,
-                            NATIVE_TAIL_ENCODE=False)
+    base_box = {}
+    with native_unbuilt():
+        base, _ = _run_workload(0, app_hook=_capture(base_box))
+    assert _fee_metrics(base_box["app"]).get(
+        "apply.native.tail_encode.hit", 0) == 0
     box = {}
-    fps, _ = _run_workload(0, NATIVE_APPLY=False,
-                           app_hook=_capture(box))
+    fps, _ = _run_workload(0, app_hook=_capture(box))
     _assert_identical(base, fps, "native tail encode")
     mets = _fee_metrics(box["app"])
     assert mets.get("apply.native.tail_encode.hit", 0) > 0, mets
 
 
 def test_all_three_kill_switches_off_matches_all_on():
-    """Belt and braces: every r16 feature off at once vs everything on
-    at workers=4, same pool workload, same bytes — the combined
-    kill-switch arm an operator would actually reach for."""
-    base, _, _ = _pool_workload(0, NATIVE_APPLY=False, NATIVE_FEE=False,
-                                NATIVE_POOL_QUOTE=False,
-                                NATIVE_TAIL_ENCODE=False)
+    """Belt and braces: a host without the native code (no apply
+    kernel, fee batch, pool quoting or tail encode) vs everything on at
+    workers=4, same pool workload, same bytes."""
+    with native_unbuilt():
+        base, _, _ = _pool_workload(0)
     fps, stats, _ = _pool_workload(4)
     _assert_identical(base, fps, "all-on vs all-off")
     assert stats["native_hits"] > 0, stats
@@ -198,7 +205,7 @@ def test_generateload_mode_pool_admin_endpoint():
     ledger drives the pool hops through the native kernel."""
     app = Application(VirtualClock(ClockMode.VIRTUAL_TIME), test_config(
         TESTING_UPGRADE_MAX_TX_SET_SIZE=300,
-        PARALLEL_APPLY_WORKERS=2, NATIVE_APPLY=True))
+        PARALLEL_APPLY_WORKERS=2))
     app.start()
     handler = CommandHandler(app)
     code, body = handler.handle("generateload",
@@ -251,8 +258,12 @@ import sys
 sys.path.insert(0, {repo!r})
 from tests.test_native_fee import _pool_workload
 
-fps, stats, _ = _pool_workload({workers}, n_closes=2, txs=20)
-assert stats["native_hits"] > 0, stats
+box = {{}}
+fps, stats, _ = _pool_workload({workers}, n_closes=2, txs=20,
+                               app_hook=lambda app: box.update(app=app))
+fee_hits = box["app"].metrics.counter("apply.native.fee.hit").count
+assert fee_hits > 0, fee_hits
+assert stats["native_hits"] > 0 or {workers} == 0, stats
 for lh, bh, meta in fps:
     print(lh.hex(), bh.hex(), hashlib.sha256(meta).hexdigest())
 """

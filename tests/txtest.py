@@ -2,6 +2,7 @@
 (ref model: src/test/TestAccount.h, TxTests.cpp op builders)."""
 from __future__ import annotations
 
+import contextlib
 from typing import List, Optional
 
 from stellar_core_tpu.crypto import SecretKey, sha256
@@ -16,6 +17,24 @@ NETWORK_ID = sha256(NETWORK_PASSPHRASE)
 BASE_FEE = 100
 BASE_RESERVE = 5000000
 GENESIS_BALANCE = 10**17  # ~10B XLM in stroops
+
+
+@contextlib.contextmanager
+def native_unbuilt():
+    """A host on which the native code did not build: the apply kernel
+    (``native_apply.kernel_module``) and the batched XDR packer
+    (``runtime.encode_many``) both report absent, so closes run the
+    Python reference apply, fee loop and tail encode.  Enter it before
+    the app under test is built (its parallel-apply manager probes the
+    kernel once, at construction) and leave it after the app stops."""
+    from unittest import mock
+
+    from stellar_core_tpu.apply import native_apply
+    from stellar_core_tpu.xdr import runtime
+
+    with mock.patch.object(native_apply, "kernel_module", lambda: None), \
+            mock.patch.object(runtime, "encode_many", lambda pairs: None):
+        yield
 
 
 def genesis_header(ledger_seq=1, close_time=1000, protocol_version=19):

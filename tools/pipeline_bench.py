@@ -68,7 +68,6 @@ def _mk_app(workers: int, node_dir=None):
         PIPELINED_CLOSE=True,
         PIPELINED_CLOSE_EAGER_DRAIN=False,  # measure the real overlap
         PARALLEL_APPLY_WORKERS=workers,
-        NATIVE_APPLY_INLINE=workers < 2,
         **kw))
     app.start()
     app.herder.manual_close()  # applies the max-tx-set-size upgrade

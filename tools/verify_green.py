@@ -38,14 +38,6 @@ Usage: python tools/verify_green.py            -> exit 0 iff green
            (tools/soak_bench.py --smoke: vitals ring populated, memory
            slope under the SLO ceiling, zero breaches, telemetry
            disabled-cost <1% and on/off hash parity);
-           --skip-credit-smoke skips the kernel-complete credit gate
-           (tools/parallel_apply_bench.py --credit-smoke: credit-mix +
-           path-payment closes bit-identical native-vs-Python AND
-           native cluster-hit rate >= 0.9 — declines on those mixes
-           are bugs now); --skip-fee-smoke skips the batched fee-phase
-           gate (tools/parallel_apply_bench.py --fee-smoke: NATIVE_FEE
-           on/off closes bit-identical AND the charge_fees batch
-           carries >= 90% of closes on the mixed workload);
            --skip-catchup-smoke skips the cold-join catchup gate
            (tools/catchup_bench.py --smoke: a cold node joins a live
            core-2 net mid-traffic, catches up via verified bucket
@@ -118,16 +110,12 @@ def print_timings(log: str, top_n: int = 10) -> None:
         print(f"  {f:<{width}}  {s:8.2f}s", flush=True)
 
 
-def run_parallel_smoke(cmd: str, native: bool = True) -> "tuple":
-    """The tier-1 line again with parallel apply forced on.  With
-    ``native=False`` the NATIVE_APPLY=0 kill switch is exported too —
-    the fallback-parity smoke: the Python reference apply must keep the
-    suite green on its own (kernel declines land there).  Returns
+def run_parallel_smoke(cmd: str) -> "tuple":
+    """The tier-1 line again with parallel apply forced on.  Returns
     (problems, passed, abort_summary)."""
-    tag = "parallel" if native else "fallback"
-    log_path = f"/tmp/_t1p_{tag}.log"
+    log_path = "/tmp/_t1p_parallel.log"
     smoke_cmd = cmd.replace("/tmp/_t1.log", log_path)
-    stats_path = f"/tmp/_t1p_{tag}_apply_stats.jsonl"
+    stats_path = "/tmp/_t1p_parallel_apply_stats.jsonl"
     try:
         os.unlink(stats_path)
     except OSError:
@@ -135,25 +123,24 @@ def run_parallel_smoke(cmd: str, native: bool = True) -> "tuple":
     env = dict(os.environ)
     env["PARALLEL_APPLY_WORKERS"] = "2"
     env["PARALLEL_APPLY_STATS_FILE"] = stats_path
-    env["NATIVE_APPLY"] = "1" if native else "0"
-    print(f"verify_green: [{tag} smoke] PARALLEL_APPLY_WORKERS=2 "
-          f"NATIVE_APPLY={env['NATIVE_APPLY']} {smoke_cmd}", flush=True)
+    print("verify_green: [parallel smoke] PARALLEL_APPLY_WORKERS=2 "
+          f"{smoke_cmd}", flush=True)
     proc = subprocess.run(["bash", "-c", smoke_cmd], cwd=REPO, env=env)
     problems = []
     if proc.returncode != 0:
-        problems.append(f"{tag} smoke exited {proc.returncode}")
+        problems.append(f"parallel smoke exited {proc.returncode}")
     try:
         with open(log_path, errors="replace") as f:
             log = f.read()
     except OSError:
-        problems.append(f"{tag} smoke log missing")
+        problems.append("parallel smoke log missing")
         log = ""
     tail = "\n".join(log.splitlines()[-30:])
     for pat, what in ((r"\b([1-9]\d*) failed\b", "failed tests"),
                       (r"\b([1-9]\d*) errors?\b", "collection errors")):
         m = re.search(pat, tail)
         if m:
-            problems.append(f"{tag} smoke: {m.group(1)} {what}")
+            problems.append(f"parallel smoke: {m.group(1)} {what}")
     m = re.search(r"\b(\d+) passed\b", tail)
     passed = m.group(1) if m else "?"
     totals = {"parallel_closes": 0, "sequential_closes": 0, "aborts": 0,
@@ -273,86 +260,6 @@ def run_pipelined_smoke(cmd: str) -> "tuple":
                f"{totals['prefetch_adopted']} prefetched keys adopted, "
                f"on/off parity {parity}")
     return problems, passed, summary
-
-
-def run_credit_native_smoke() -> "tuple":
-    """The ISSUE-13 kernel-complete gate: a small credit-mix and
-    path-payment workload must (a) close bit-identical native-vs-Python
-    and (b) hit the kernel on >= 90% of clusters — declines on the
-    kernel-complete mixes are bugs now, not expected coverage gaps.
-    Returns (problems, summary)."""
-    out = "/tmp/_t1_credit_smoke.json"
-    cmd = [sys.executable, "-m", "tools.parallel_apply_bench",
-           "--credit-smoke", "--out", out]
-    print(f"verify_green: [credit-native smoke] {' '.join(cmd)}",
-          flush=True)
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    proc = subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
-                          text=True, timeout=900)
-    try:
-        with open(out) as f:
-            rep = json.load(f)
-    except (OSError, ValueError) as e:
-        return [f"credit-native smoke report unreadable: {e} "
-                f"(exit {proc.returncode})"], "failed"
-    problems = []
-    for shape, row in sorted(rep.get("shapes", {}).items()):
-        if not row.get("parity_identical"):
-            problems.append(f"credit-native smoke: {shape} native/Python "
-                            "parity DIVERGED")
-        if row.get("aborts"):
-            problems.append(
-                f"credit-native smoke: {shape} {row['aborts']} aborts")
-        if row.get("native_hit_rate", 0.0) < 0.9:
-            problems.append(
-                f"credit-native smoke: {shape} hit rate "
-                f"{row.get('native_hit_rate')} < 0.9 "
-                f"(declines: {row.get('decline_reasons')})")
-    if proc.returncode != 0 and not problems:
-        problems.append(f"credit-native smoke exited {proc.returncode}")
-    summary = ", ".join(
-        f"{shape} hit_rate={row.get('native_hit_rate')} "
-        f"parity={'ok' if row.get('parity_identical') else 'FAILED'}"
-        for shape, row in sorted(rep.get("shapes", {}).items()))
-    return problems, summary or "no shapes reported"
-
-
-def run_fee_native_smoke() -> "tuple":
-    """The ISSUE-16 fee-phase gate: a mixed workload with the batched
-    charge_fees kernel on vs NATIVE_FEE=0 must close bit-identical
-    (hashes AND meta bytes), and the fee batch must carry >= 90% of
-    closes (whole-batch declines on clean traffic are bugs now).
-    Returns (problems, summary)."""
-    out = "/tmp/_t1_fee_smoke.json"
-    cmd = [sys.executable, "-m", "tools.parallel_apply_bench",
-           "--fee-smoke", "--out", out]
-    print(f"verify_green: [fee-native smoke] {' '.join(cmd)}",
-          flush=True)
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    proc = subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
-                          text=True, timeout=900)
-    try:
-        with open(out) as f:
-            rep = json.load(f)
-    except (OSError, ValueError) as e:
-        return [f"fee-native smoke report unreadable: {e} "
-                f"(exit {proc.returncode})"], "failed"
-    problems = []
-    if not rep.get("parity_identical"):
-        problems.append(
-            "fee-native smoke: NATIVE_FEE on/off parity DIVERGED")
-    if rep.get("fee_batch_hit_rate", 0.0) < 0.9:
-        problems.append(
-            f"fee-native smoke: fee-batch hit rate "
-            f"{rep.get('fee_batch_hit_rate')} < 0.9 "
-            f"(counters: {rep.get('fee_batch')})")
-    if proc.returncode != 0 and not problems:
-        problems.append(f"fee-native smoke exited {proc.returncode}")
-    summary = (f"hit_rate={rep.get('fee_batch_hit_rate')} "
-               f"parity={'ok' if rep.get('parity_identical') else 'FAILED'}")
-    return problems, summary
 
 
 def run_chaos_smoke() -> "tuple":
@@ -853,12 +760,9 @@ def main() -> int:
         return 0
     smoke_only = "--parallel-smoke-only" in sys.argv
     skip_smoke = "--skip-parallel-smoke" in sys.argv
-    skip_fallback = "--skip-fallback-smoke" in sys.argv
     skip_chaos = "--skip-chaos-smoke" in sys.argv
     skip_pipeline = "--skip-pipeline-smoke" in sys.argv
     skip_soak = "--skip-soak-smoke" in sys.argv
-    skip_credit = "--skip-credit-smoke" in sys.argv
-    skip_fee = "--skip-fee-smoke" in sys.argv
     skip_forensics = "--skip-forensics-smoke" in sys.argv
     skip_catchup = "--skip-catchup-smoke" in sys.argv
     skip_lockdep = "--skip-lockdep-smoke" in sys.argv
@@ -922,28 +826,6 @@ def main() -> int:
               flush=True)
         problems.extend(smoke_problems)
         smoke_note = f"parallel smoke passed={smoke_passed}"
-        if not skip_fallback:
-            # NATIVE_APPLY=0 fallback parity: the Python reference
-            # apply alone must keep the suite green (every kernel
-            # decline lands on it in production)
-            fb_problems, fb_passed, fb_summary = run_parallel_smoke(
-                cmd, native=False)
-            print(f"verify_green: fallback-parity smoke: {fb_summary}",
-                  flush=True)
-            problems.extend(fb_problems)
-            smoke_note += f", fallback smoke passed={fb_passed}"
-    if not skip_credit:
-        cr_problems, cr_summary = run_credit_native_smoke()
-        print(f"verify_green: credit-native smoke: {cr_summary}",
-              flush=True)
-        problems.extend(cr_problems)
-        smoke_note += f", credit smoke: {cr_summary}"
-    if not skip_fee:
-        fee_problems, fee_summary = run_fee_native_smoke()
-        print(f"verify_green: fee-native smoke: {fee_summary}",
-              flush=True)
-        problems.extend(fee_problems)
-        smoke_note += f", fee smoke: {fee_summary}"
     if not skip_pipeline:
         pl_problems, pl_passed, pl_summary = run_pipelined_smoke(cmd)
         print(f"verify_green: pipelined-close smoke: {pl_summary}",
