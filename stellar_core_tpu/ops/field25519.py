@@ -90,6 +90,43 @@ def _split(x):
     return lo, carry
 
 
+def _carry_pass(x, below, wrap=1):
+    """One parallel carry pass: each limb's low 12 bits plus the carry out
+    of the limb below it, given as `below` (x moved up one limb), times
+    `wrap`."""
+    lo, _ = _split(x)
+    return lo + wrap * (below >> RADIX)
+
+
+def _rot(x):
+    """Limbs moved up one place, limb 21 wrapping round to limb 0."""
+    return jnp.concatenate([x[..., -1:], x[..., :-1]], axis=-1)
+
+
+# a wraparound pass's weights: limb 21's carry lands on limb 0 times FOLD
+_WRAP = np.array([FOLD] + [1] * (NLIMBS - 1), dtype=np.int32)
+
+
+def _wrap_passes(x, passes: int, top=None):
+    """`passes` wraparound carry passes over the 22 limbs; `top`, a value
+    of weight 2^264 if given, lands on limb 0 times FOLD after the first.
+
+    A pass reads the limb below, so each is computed from rotated copies of
+    the input, never from the pass before it: XLA fuses the passes, where
+    it would keep each pass's output as an op of its own."""
+    views = [x]  # views[j]: the value rotated up j limbs
+    for _ in range(passes):
+        views.append(_rot(views[-1]))
+    for p in range(passes):
+        views = [_carry_pass(views[j], views[j + 1], np.roll(_WRAP, j))
+                 for j in range(len(views) - 1)]
+        if p == 0 and top is not None:
+            limb0 = np.eye(NLIMBS, dtype=np.int32)  # row j: limb 0 of view j
+            views = [v + limb0[j] * (FOLD * top)
+                     for j, v in enumerate(views)]
+    return views[0]
+
+
 def weak_carry(x: jnp.ndarray, passes: int = 2) -> jnp.ndarray:
     """Parallel carry passes on a 22-limb value; carry out of limb 21 folds
     back onto limb 0 with weight 19*2^3 (2^(12*22)=2^264 ... limb21's carry has
@@ -98,13 +135,7 @@ def weak_carry(x: jnp.ndarray, passes: int = 2) -> jnp.ndarray:
     With input |limb| <= 2^17 the result after 2 passes has limbs in
     [-3, BASE+3] — comfortably mul-safe.
     """
-    for _ in range(passes):
-        lo, carry = _split(x)
-        wrapped = carry[..., 21:22] * FOLD
-        carry = jnp.concatenate(
-            [wrapped, carry[..., :21]], axis=-1)
-        x = lo + carry
-    return x
+    return _wrap_passes(x, passes)
 
 
 def _carry_full(x: jnp.ndarray, width: int) -> jnp.ndarray:
@@ -150,12 +181,22 @@ def mul_small(a, k: int):
 def _convolve(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Schoolbook product: (..., 22) x (..., 22) -> (..., 44) int32.
     Requires mul-safe inputs.  Position 43 is always zero (kept for the carry
-    pass out of position 42)."""
-    shape = a.shape[:-1]
-    c = jnp.zeros((*shape, 2 * NLIMBS), dtype=jnp.int32)
-    for i in range(NLIMBS):
-        c = c.at[..., i:i + NLIMBS].add(a[..., i:i + 1] * b)
-    return c
+    pass out of position 42).
+
+    Row i is a_i times b padded into place, and the 22 rows are summed as a
+    pairwise tree: one expression, which XLA fuses into one op with the
+    products inside (padding b rather than the product keeps the products
+    from being written out).  Integer sums do not depend on their order, so
+    the limbs are those of a plain accumulation, and no partial sum exceeds
+    the bound of the full one."""
+    lead = [(0, 0)] * (b.ndim - 1)
+    rows = [a[..., i:i + 1] * jnp.pad(b, lead + [(i, NLIMBS - i)])
+            for i in range(NLIMBS)]
+    while len(rows) > 1:
+        pairs = [rows[k] + rows[k + 1] for k in range(0, len(rows) - 1, 2)]
+        rows = pairs + rows[2 * len(pairs):]
+    return rows[0]
+
 
 def _reduce_product(c: jnp.ndarray) -> jnp.ndarray:
     """(..., 44) convolution -> (..., 22) mul-safe field element.
@@ -164,21 +205,19 @@ def _reduce_product(c: jnp.ndarray) -> jnp.ndarray:
     no carry is ever dropped) bring |limb| from <2^31 to <= BASE+130.
     Stage 2: fold positions 22..44 onto 0..22 with weight FOLD
     (2^(12k) == FOLD * 2^(12(k-22)) mod p); magnitudes <= ~2^25.4.
-    Stage 3: three wraparound passes over the 23-wide result, folding the
-    weight-2^264 accumulator (position 22) into limb 0 each pass."""
+    Stage 3: three wraparound passes over the 22 limbs; stage 2's fold of
+    position 44, of weight 2^264, lands on limb 0 in the first.
+
+    As in `_wrap_passes`, stage 1's second pass is computed from shifted
+    copies of the first pass's input, so XLA fuses the two passes."""
     lead = [(0, 0)] * (c.ndim - 1)
     c = jnp.pad(c, lead + [(0, 2)])  # width 46; positions 43..45 are zero
-    for _ in range(2):
-        lo, carry = _split(c)
-        c = lo + jnp.pad(carry[..., :-1], lead + [(1, 0)])
-    out = jnp.pad(c[..., :NLIMBS], lead + [(0, 1)]) + FOLD * c[..., NLIMBS:45]
-    for _ in range(3):
-        lo, carry = _split(out[..., :NLIMBS])
-        top = out[..., NLIMBS] + carry[..., NLIMBS - 1]  # weight 2^264
-        body = lo + jnp.pad(carry[..., :NLIMBS - 1], lead + [(1, 0)])
-        body = body.at[..., 0].add(FOLD * top)
-        out = jnp.pad(body, lead + [(0, 1)])
-    return out[..., :NLIMBS]
+    up1 = jnp.pad(c[..., :-1], lead + [(1, 0)])
+    up2 = jnp.pad(c[..., :-2], lead + [(2, 0)])
+    c = _carry_pass(_carry_pass(c, up1), _carry_pass(up1, up2))
+    x = c[..., :NLIMBS] + FOLD * c[..., NLIMBS:2 * NLIMBS]
+    top = FOLD * c[..., 2 * NLIMBS:2 * NLIMBS + 1]  # weight 2^264
+    return _wrap_passes(x, 3, top)
 
 
 def mul(a, b):

@@ -11,6 +11,7 @@ process at a time may load the TPU library, and every xdist worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,11 +63,34 @@ def test_pallas_verify_compiles_for_v5e(one_chip, bucket):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _while_bodies(hlo_text):
+    """{name: instruction lines} of every computation a while loop runs."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", hlo_text))
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"%?([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+    return {b: comps[b] for b in bodies}
+
+
 def test_xla_verify_compiles_for_v5e(one_chip):
     from stellar_core_tpu.ops.ed25519_kernel import verify_batch
 
     compiled = verify_batch.lower(*_sig_batch(256, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+    # the ladder, the body with the most instructions, runs 64 times: each
+    # op in it is paid per step, so the field multiply has to fuse
+    ladder = max(_while_bodies(compiled.as_text()).values(), key=len)
+    updates = sum(" dynamic-update-slice(" in line for line in ladder)
+    fusions = sum(" fusion(" in line for line in ladder)
+    assert updates == 0, f"{updates} dynamic-update-slice ops in the ladder"
+    assert fusions < 700, f"{fusions} fusions in the ladder"
 
 
 def _qsets(sharding, batch=()):

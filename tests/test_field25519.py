@@ -114,3 +114,70 @@ def test_eq_parity():
     assert got.tolist() == [True, False, False, True, False]
     par = np.asarray(F.parity(_batch([2, 3, P - 1, P - 2])))
     assert par.tolist() == [0, 1, (P - 1) & 1, (P - 2) & 1]
+
+
+def _mul_model(a, b):
+    """F.mul spelled out in NumPy int64: the schoolbook product, then the
+    reduction's three stages pass by pass, each pass written out."""
+    n = a.shape[0]
+    c = np.zeros((n, 2 * F.NLIMBS + 2), dtype=np.int64)
+    for i in range(F.NLIMBS):
+        for j in range(F.NLIMBS):
+            c[:, i + j] += a[:, i] * b[:, j]
+    assert np.abs(c).max() < 2**31  # the product fits int32
+    for _ in range(2):
+        carry = c >> F.RADIX
+        c = c - (carry << F.RADIX)
+        c[:, 1:] += carry[:, :-1]
+    out = np.zeros((n, F.NLIMBS + 1), dtype=np.int64)
+    out[:, :F.NLIMBS] = c[:, :F.NLIMBS]
+    out += F.FOLD * c[:, F.NLIMBS:2 * F.NLIMBS + 1]
+    for _ in range(3):
+        carry = out[:, :F.NLIMBS] >> F.RADIX
+        body = out[:, :F.NLIMBS] - (carry << F.RADIX)
+        body[:, 1:] += carry[:, :-1]
+        body[:, 0] += F.FOLD * (out[:, F.NLIMBS] + carry[:, -1])
+        out = np.zeros_like(out)
+        out[:, :F.NLIMBS] = body
+    return out[:, :F.NLIMBS]
+
+
+def _mul_safe_limbs(rng, n, kind):
+    bound = np.full(F.NLIMBS, F.MUL_SAFE)
+    bound[0] = F.MUL_SAFE_0
+    if kind == "extreme":  # every limb at +-its bound, signs mixed per row
+        return (bound * rng.choice([-1, 1], (n, F.NLIMBS))).astype(np.int32)
+    x = rng.integers(-bound, bound + 1, (n, F.NLIMBS))
+    if kind == "negative":
+        x = -np.abs(x)
+    return x.astype(np.int32)
+
+
+@pytest.mark.parametrize("kind", ["random", "extreme", "negative"])
+def test_mul_limbs_equal_int64_model(kind):
+    # limb for limb, not only mod p: the fused multiply computes the same
+    # integers as the plain schoolbook product and reduction
+    rng = np.random.default_rng({"random": 7, "extreme": 8, "negative": 9}[kind])
+    a = _mul_safe_limbs(rng, 1024, kind)
+    b = _mul_safe_limbs(rng, 1024, kind)
+    if kind == "extreme":  # all-max and all-min rows against each other
+        bound = np.abs(a[0])
+        a[:2], b[:2] = [bound, -bound], [bound, bound]
+    got = np.asarray(F.mul(jnp.asarray(a), jnp.asarray(b)))
+    want = _mul_model(a.astype(np.int64), b.astype(np.int64))
+    assert np.array_equal(got, want)
+    assert np.abs(got[:, 1:]).max() <= F.MUL_SAFE
+    assert np.abs(got[:, 0]).max() <= F.MUL_SAFE_0
+
+
+@pytest.mark.parametrize("passes", [1, 2, 3])
+def test_weak_carry_limbs_equal_int64_model(passes):
+    rng = np.random.default_rng(10 + passes)
+    x = rng.integers(-2**24, 2**24, (1024, F.NLIMBS))
+    got = np.asarray(F.weak_carry(jnp.asarray(x, dtype=jnp.int32), passes))
+    for _ in range(passes):
+        carry = x >> F.RADIX
+        x = x - (carry << F.RADIX)
+        x[:, 1:] += carry[:, :-1]
+        x[:, 0] += F.FOLD * carry[:, -1]
+    assert np.array_equal(got, x)
